@@ -31,6 +31,8 @@ def main() -> None:
                          "roofline,round_engine,sweep_scaling,fleet_scaling")
     args = ap.parse_args()
     fast = not args.full
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     if args.smoke:
         from benchmarks import round_engine

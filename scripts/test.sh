@@ -16,6 +16,12 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
+# Every leg runs on the CPU, on a machine with a TPU too: the suite's
+# bitwise packed-vs-reference contract holds for the XLA kernel mirrors
+# that kernel_impl="auto" picks off-TPU (the Pallas kernels run in
+# interpret mode there). The chip path is checked by `python chip_smoke.py`.
+export JAX_PLATFORMS=cpu
+
 MARKER=(-m "not slow")
 if [[ "${1:-}" == "--all" ]]; then
     MARKER=()

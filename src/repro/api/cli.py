@@ -37,6 +37,7 @@ from repro.api.registry import DATASETS, LOCAL_SCHEMES, MODELS, SCHEMES
 from repro.api.spec import ExperimentSpec
 from repro.api.sweep import MANIFEST_NAME, JsonlDirSink, SweepSpec, run_sweep
 from repro.core.aggregators import make_aggregator
+from repro.launch.cache import use_compile_cache
 
 
 def _print_result(res: RunResult) -> None:
@@ -218,6 +219,7 @@ def _cmd_sweep(args) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    use_compile_cache()
     p = argparse.ArgumentParser(
         prog="python -m repro.api.cli",
         description="Run / resume / validate declarative FEEL experiments.")

@@ -100,7 +100,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.packing import ParamPack
@@ -139,8 +139,8 @@ def kth_smallest_threshold(q: jnp.ndarray, prunable: jnp.ndarray,
 
     `hist_impl` picks how the histogram pass is computed when
     ``coarse="histogram"``: "pallas" uses the tiled exponent-histogram
-    kernel (per-block bin counts accumulated in VMEM scratch — no
-    scatter-add; requires a packed [R, 128*k] layout), "xla" the
+    kernel (per-lane bin counts, no scatter-add; requires a packed
+    [R, 128*k] layout and raises on any other), "xla" the
     scatter-add mirror, "auto" pallas on TPU and xla elsewhere
     (`kernels/ops.packed_exponent_histogram`).
     """
@@ -629,15 +629,35 @@ class RoundEngine:
         # finite — exactly the quarantine's surviving weights
         return w2, g, step, n_ok, ast, cw_eff
 
+    def _threshold_mask(self, w, v, k):
+        """Shared-lambda prologue: the round's threshold and keep-mask."""
+        q = (w * v) ** 2
+        thr = kth_smallest_threshold(q, self.prunable, k)
+        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
+                                             impl=self.kernel_impl)
+        return thr, mask
+
+    def _thresholds(self, w, v, ks):
+        """Per-client-lambda prologue: one threshold per client, [C]."""
+        return kth_smallest_threshold((w * v) ** 2, self.prunable, ks)
+
+    def _replicated(self, fn, *args):
+        """``fn(*args)``, which a mesh runs on every device's full copy of
+        the replicated operands. XLA cannot partition a Pallas kernel, so on
+        a mesh each replicated stretch of a round that may hold one — the
+        threshold and mask prologue, the aggregate tails — runs in a
+        shard_map of its own (same ops, so the same bits)."""
+        if self.mesh is None:
+            return fn(*args)
+        return shard_map(fn, mesh=self.mesh, in_specs=(P(),) * len(args),
+                         out_specs=P(), check_vma=False)(*args)
+
     def _round_shared(self, w, v, xs, ys, sw, cw, inv, k, noise=None,
                       cf=None, poison=None):
         """One shared-lambda round, given device batches — the single body
         traced by both the per-round jit and the block scan, so the two
         paths compile the identical round math (bit-for-bit contract)."""
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, k)
-        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
-                                             impl=self.kernel_impl)
+        thr, mask = self._threshold_mask(w, v, k)
         pruned = w * mask
         losses, grads = self._client_grads_shared(pruned, mask, xs, ys, sw)
         # step stays an output of the jitted graph: see the weighted update
@@ -648,8 +668,7 @@ class RoundEngine:
     def _round_multi(self, w, v, xs, ys, sw, cw, inv, ks, noise=None,
                      cf=None, poison=None):
         """One per-client-lambda round (see _round_shared)."""
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, ks)      # [C]
+        thr = self._thresholds(w, v, ks)                       # [C]
         _, masks = ops.packed_importance_masks(w, v, self.prunable, thr,
                                                impl=self.kernel_impl)
         losses, grads = self._client_grads_multi(w, masks, xs, ys, sw)
@@ -684,10 +703,7 @@ class RoundEngine:
 
     def _round_shared_dyn(self, w, v, xs, ys, sw, cw, inv, k, h, cid,
                           noise=None, cf=None, poison=None):
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, k)
-        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
-                                             impl=self.kernel_impl)
+        thr, mask = self._threshold_mask(w, v, k)
         pruned = w * mask
         losses, uploads, hds = self._locals_shared(pruned, mask, xs, ys, sw,
                                                    h[cid])
@@ -698,8 +714,7 @@ class RoundEngine:
 
     def _round_multi_dyn(self, w, v, xs, ys, sw, cw, inv, ks, h, cid,
                          noise=None, cf=None, poison=None):
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, ks)      # [C]
+        thr = self._thresholds(w, v, ks)                       # [C]
         _, masks = ops.packed_importance_masks(w, v, self.prunable, thr,
                                                impl=self.kernel_impl)
         losses, uploads, hds = self._locals_multi(w, masks, xs, ys, sw,
@@ -729,10 +744,7 @@ class RoundEngine:
 
     def _round_shared_dyn_sharded(self, w, v, xs, ys, sw, cw, inv, k, h,
                                   cid, noise=None, cf=None, poison=None):
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, k)
-        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
-                                             impl=self.kernel_impl)
+        thr, mask = self._replicated(self._threshold_mask, w, v, k)
         pruned = w * mask
         hc = h[cid]
 
@@ -744,20 +756,20 @@ class RoundEngine:
             return losses, ga, hda
 
         # gather-then-reduce is replicated by construction but invisible to
-        # the static replication checker (see _robust_partial)
+        # the varying-axes check (see _round_shared_sharded)
         losses, ups, hds = shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(), P("data"), P("data"), P("data"), P("data")),
-            out_specs=(P("data"), P(), P()), check_rep=False)(
+            out_specs=(P("data"), P(), P()), check_vma=False)(
                 pruned, mask, xs, ys, sw, hc)
-        w2, g, step, n_ok, ast, h2 = self._dyn_sharded_tail(
-            w, v, ups, hds, cw, inv, h, cid, noise, cf, poison)
+        w2, g, step, n_ok, ast, h2 = self._replicated(
+            self._dyn_sharded_tail, w, v, ups, hds, cw, inv, h, cid, noise,
+            cf, poison)
         return w2, g, losses, thr, step, n_ok, ast, h2
 
     def _round_multi_dyn_sharded(self, w, v, xs, ys, sw, cw, inv, ks, h,
                                  cid, noise=None, cf=None, poison=None):
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, ks)      # [C]
+        thr = self._replicated(self._thresholds, w, v, ks)     # [C]
         hc = h[cid]
 
         def body(w_, v_, pr, thr_, xs_, ys_, sw_, hc_):
@@ -773,10 +785,11 @@ class RoundEngine:
             body, mesh=self.mesh,
             in_specs=(P(), P(), P(), P("data"), P("data"), P("data"),
                       P("data"), P("data")),
-            out_specs=(P("data"), P(), P()), check_rep=False)(
+            out_specs=(P("data"), P(), P()), check_vma=False)(
                 w, v, self.prunable, thr, xs, ys, sw, hc)
-        w2, g, step, n_ok, ast, h2 = self._dyn_sharded_tail(
-            w, v, ups, hds, cw, inv, h, cid, noise, cf, poison)
+        w2, g, step, n_ok, ast, h2 = self._replicated(
+            self._dyn_sharded_tail, w, v, ups, hds, cw, inv, h, cid, noise,
+            cf, poison)
         return w2, g, losses, thr, step, n_ok, ast, h2
 
     # -- block scaffold: lax.scan over the round axis -----------------------
@@ -1088,9 +1101,9 @@ class RoundEngine:
     def _round_shared_sharded(self, w, v, xs, ys, sw, cw, inv, k, noise=None,
                               cf=None, poison=None):
         """Mesh variant of _round_shared: threshold / mask / FedSGD update
-        replicated OUTSIDE the shard_map region (the shard_map replication
-        checker has no rule for the `while` ops inside the threshold
-        search and the FMA fence), per-shard gradient scan + the round's
+        replicated outside the client-sharded region (each in its own
+        replicated shard_map where a Pallas kernel may run, see
+        `_replicated`), per-shard gradient scan + the round's
         single collective inside (the mean path's psum, or the robust
         path's all_gather when an aggregator is set — the reducers need
         the full client stack). Traced by both the per-round jit and the
@@ -1098,16 +1111,18 @@ class RoundEngine:
         joins the replicated update tail — the collective count is
         unchanged. `cf` / `poison` (per-client fault operands) shard with
         the client axis."""
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, k)
-        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
-                                             impl=self.kernel_impl)
+        thr, mask = self._replicated(self._threshold_mask, w, v, k)
         pruned = w * mask
 
         robust = self.aggregator is not None
         partial = self._robust_partial if robust else self._guarded_partial
 
         def body(pruned, mask, xs, ys, sw, cw, *extra):
+            # the replicated buffer enters as device-varying: a gradient
+            # taken w.r.t. an invariant input is psum'd over the mesh by
+            # shard_map's autodiff, which would sum every shard's clients
+            # into each local gradient before the round's own psum
+            pruned = jax.lax.pcast(pruned, "data", to="varying")
             losses, grads = self._client_grads_shared(pruned, mask, xs, ys,
                                                       sw)
             return partial(losses, grads, cw,
@@ -1121,14 +1136,15 @@ class RoundEngine:
         if poison is not None:
             specs, args = specs + (P("data"),), args + (poison,)
         # the robust tail reduces the all_gather'd full stack identically
-        # on every shard — genuinely replicated, but the static replication
-        # checker has no rule for gather-then-reduce (unlike psum), so the
+        # on every shard — genuinely replicated, but the varying-axes check
+        # types an all_gather's result as varying (unlike psum's), so the
         # check is disabled on that path only
         losses, a, b = shard_map(
             body, mesh=self.mesh, in_specs=specs,
-            out_specs=(P("data"), P(), P()), check_rep=not robust)(*args)
+            out_specs=(P("data"), P(), P()), check_vma=not robust)(*args)
         if robust:
-            w2, g, step, n_ok, ast = self._robust_tail(w, v, a, b, noise)
+            w2, g, step, n_ok, ast = self._replicated(
+                self._robust_tail, w, v, a, b, noise)
         else:
             w2, g, step, n_ok = self._guarded_tail(w, v, a, b, inv, noise)
             ast = jnp.int32(0)
@@ -1137,8 +1153,7 @@ class RoundEngine:
     def _round_multi_sharded(self, w, v, xs, ys, sw, cw, inv, ks, noise=None,
                              cf=None, poison=None):
         """Mesh variant of _round_multi (see _round_shared_sharded)."""
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, ks)      # [C]
+        thr = self._replicated(self._thresholds, w, v, ks)     # [C]
 
         robust = self.aggregator is not None
         partial = self._robust_partial if robust else self._guarded_partial
@@ -1161,13 +1176,17 @@ class RoundEngine:
             specs, args = specs + (P("data"),), args + (cf,)
         if poison is not None:
             specs, args = specs + (P("data"),), args + (poison,)
-        # see _round_shared_sharded: robust outputs are replicated by
-        # construction (gather-then-reduce), invisible to the static check
+        # unchecked: the batched mask kernel runs inside this region, and a
+        # Pallas kernel cannot be traced under the varying-axes check (its
+        # interpret mode mixes varying blocks with invariant grid indices).
+        # Unchecked, autodiff inserts no psum either, so each gradient is
+        # taken w.r.t. the shard's own pruned copy
         losses, a, b = shard_map(
             body, mesh=self.mesh, in_specs=specs,
-            out_specs=(P("data"), P(), P()), check_rep=not robust)(*args)
+            out_specs=(P("data"), P(), P()), check_vma=False)(*args)
         if robust:
-            w2, g, step, n_ok, ast = self._robust_tail(w, v, a, b, noise)
+            w2, g, step, n_ok, ast = self._replicated(
+                self._robust_tail, w, v, a, b, noise)
         else:
             w2, g, step, n_ok = self._guarded_tail(w, v, a, b, inv, noise)
             ast = jnp.int32(0)

@@ -83,7 +83,7 @@ def decode_attention(
         kernel,
         grid=(b, hq, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, d), lambda bi, h, ki: (bi, h, 0, 0)),
             pl.BlockSpec((1, bk, d),
                          lambda bi, h, ki: (bi * hkv + h // g, ki, 0)),

@@ -89,8 +89,27 @@ def masked_update(w: jnp.ndarray, g: jnp.ndarray, mask: jnp.ndarray, eta):
 #   * "auto"   — pallas on TPU, xla elsewhere.
 # ---------------------------------------------------------------------------
 
-def _packed_block_rows(rows: int) -> int:
-    return next(c for c in (256, 128, 64, 32, 16, 8, 4, 2, 1) if rows % c == 0)
+# Bytes one [clients, block, 128] fp32 block of a client-stacked operand may
+# take. Pallas double-buffers every blocked operand inside the 16 MiB scoped
+# VMEM of a TPU v5e core, so a fixed 256-row block overflows it once
+# clients * 256 rows pass 8k (C=64 for the masks and aggregates). The rank
+# sort also keeps 2C live [block, 128] arrays (keys and values) through its
+# network, hence its smaller slab.
+_STACK_BYTES = 4 << 20
+_SORT_STACK_BYTES = 1 << 20
+
+
+def _packed_block_rows(rows: int, clients: int = 1,
+                       stack_bytes: int = _STACK_BYTES) -> int:
+    """Row block for a packed kernel: the largest multiple of 8 up to 256
+    that divides `rows` and keeps a [clients, block, 128] fp32 slab within
+    `stack_bytes` (never below 8 rows); the full row count when no multiple
+    of 8 divides it (a full-extent block is always a legal tile)."""
+    fits = [b for b in (256, 128, 64, 32, 16, 8) if rows % b == 0]
+    if not fits:
+        return rows
+    small = [b for b in fits if clients * b * LANES * 4 <= stack_bytes]
+    return small[0] if small else fits[-1]
 
 
 def _resolve_impl(impl: str) -> str:
@@ -122,7 +141,7 @@ def packed_importance_masks(w, v, prunable, thresholds, *, impl="auto"):
     if _resolve_impl(impl) == "pallas":
         return _pm.importance_mask_batched(
             w, v, prunable, thresholds,
-            block_rows=_packed_block_rows(w.shape[0]))
+            block_rows=_packed_block_rows(w.shape[0], thresholds.shape[0]))
     q = jnp.square(w.astype(jnp.float32) * v.astype(jnp.float32))
     keep = (q[None] >= thresholds[:, None, None]).astype(jnp.float32)
     return q, jnp.where(prunable[None] > 0, keep, 1.0)
@@ -135,13 +154,16 @@ def packed_exponent_histogram(q, prunable, *, impl="auto"):
     The coarse first pass of ``kth_smallest_threshold(coarse="histogram")``
     (core/round_engine.py): bin b counts coordinates with
     ``bits(q) >> 23 == b`` and prunable > 0. ``impl="pallas"`` runs the
-    tiled kernel (per-block bin counts in VMEM scratch, compare-reduce
-    instead of scatter — requires the packed [R, 128*k] layout and falls
-    back to the mirror otherwise); "xla" is the scatter-add mirror, exact
-    everywhere but ~130 ns/element on CPU (why coarse="auto" keeps plain
-    bisection there, see ROADMAP)."""
-    if _resolve_impl(impl) == "pallas" and q.ndim == 2 \
-            and q.shape[1] % LANES == 0:
+    tiled kernel (per-lane bin counts, compare-reduce instead of scatter)
+    and needs the packed [R, 128*k] layout — any other shape raises rather
+    than silently leaving the kernel; "xla" is the scatter-add mirror,
+    exact everywhere but ~130 ns/element on CPU (why coarse="auto" keeps
+    plain bisection there, see ROADMAP)."""
+    if _resolve_impl(impl) == "pallas":
+        if q.ndim != 2 or q.shape[1] % LANES:
+            raise ValueError(
+                f"exponent histogram kernel needs a packed [R, {LANES}*k] "
+                f"buffer, got shape {q.shape}")
         return _pm.exponent_histogram(
             q, prunable, block_rows=_packed_block_rows(q.shape[0]))
     bits = jax.lax.bitcast_convert_type(q.reshape(-1), jnp.int32)
@@ -311,7 +333,8 @@ def packed_client_rank_sort(grads, cweights, *, impl="auto"):
     to weight 0 before rank selection."""
     if _resolve_impl(impl) == "pallas":
         return _pm.client_rank_sort(
-            grads, cweights, block_rows=_packed_block_rows(grads.shape[1]))
+            grads, cweights, block_rows=_packed_block_rows(
+                grads.shape[1], grads.shape[0], _SORT_STACK_BYTES))
     g = grads.astype(jnp.float32)
     key = _order_keys(g)
     invalid = ~(cweights.astype(jnp.float32) > 0.0)
@@ -466,7 +489,8 @@ def packed_fedsgd_update(w, grads, eta, *, impl="auto"):
     hardware the contraction there may differ from the reference by 1 ulp."""
     if _resolve_impl(impl) == "pallas":
         return _pm.fedsgd_aggregate(
-            w, grads, eta, block_rows=_packed_block_rows(w.shape[0]))
+            w, grads, eta,
+            block_rows=_packed_block_rows(w.shape[0], grads.shape[0]))
     g = grads[0].astype(jnp.float32)
     for c in range(1, grads.shape[0]):       # same summation order as the
         g = g + grads[c].astype(jnp.float32)  # kernel / reference trainer
@@ -489,7 +513,7 @@ def packed_fedsgd_update_weighted(w, grads, cweights, inv, eta, *,
     if _resolve_impl(impl) == "pallas":
         return _pm.fedsgd_aggregate_weighted(
             w, grads, cweights, inv, eta,
-            block_rows=_packed_block_rows(w.shape[0]))
+            block_rows=_packed_block_rows(w.shape[0], grads.shape[0]))
     return packed_apply_mean_update(
         w, packed_weighted_grad_sum(grads, cweights), inv, eta)
 

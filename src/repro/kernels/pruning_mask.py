@@ -29,14 +29,31 @@ hands whole-model [R, 128] buffers to the batched/aggregate kernels directly
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+
+# Scalar operands (thresholds, eta, 1/C, client weights) live in SMEM and
+# are read whole by every grid step: a kernel may only load from VMEM or
+# SMEM refs, never from an ANY-space (HBM) ref.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _row_blocks(r: int, c: int, block_rows: int,
+                interpret: bool | None) -> tuple[int, bool]:
+    """Validate an [r, c] packed operand against its row block; returns
+    (block rows, interpret) — interpret mode everywhere but on a TPU."""
+    if c % LANES:
+        raise ValueError(f"last dim must be a multiple of {LANES}")
+    br = min(block_rows, r)
+    if r % br:
+        raise ValueError(f"rows {r} must divide block {br}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return br, interpret
 
 
 def _importance_mask_kernel(w_ref, v_ref, thr_ref, q_ref, m_ref):
@@ -51,20 +68,14 @@ def importance_mask_2d(w, v, threshold, *, block_rows: int = 256,
                        interpret: bool | None = None):
     """w, v: [R, 128*k]; threshold scalar -> (importance fp32, mask fp32)."""
     r, c = w.shape
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     thr = jnp.asarray([threshold], jnp.float32)
     grid = (r // br,)
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     return pl.pallas_call(
         _importance_mask_kernel,
         grid=grid,
-        in_specs=[spec, spec, pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[spec, spec, _SMEM],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((r, c), jnp.float32),
                    jax.ShapeDtypeStruct((r, c), jnp.float32)],
@@ -94,21 +105,14 @@ def importance_mask_batched(w, v, prunable, thresholds, *,
     wherever `prunable` is 0 (protected / padding coordinates are kept)."""
     r, c = w.shape
     n_clients = thresholds.shape[0]
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     thr = thresholds.astype(jnp.float32)
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     mspec = pl.BlockSpec((n_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _importance_mask_batched_kernel,
         grid=(r // br,),
-        in_specs=[spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[spec, spec, spec, _SMEM],
         out_specs=[spec, mspec],
         out_shape=[jax.ShapeDtypeStruct((r, c), jnp.float32),
                    jax.ShapeDtypeStruct((n_clients, r, c), jnp.float32)],
@@ -141,21 +145,14 @@ def fedsgd_aggregate(w, grads, eta, *, block_rows: int = 256,
     next round's broadcast v."""
     r, c = w.shape
     n_clients = grads.shape[0]
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     eta_arr = jnp.asarray([eta], jnp.float32)
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     gspec = pl.BlockSpec((n_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _fedsgd_aggregate_kernel,
         grid=(r // br,),
-        in_specs=[spec, gspec,
-                  pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[spec, gspec, _SMEM],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((r, c), w.dtype),
                    jax.ShapeDtypeStruct((r, c), jnp.float32),
@@ -199,13 +196,7 @@ def fedsgd_aggregate_weighted(w, grads, cweights, inv, eta, *,
     doubles as the next round's broadcast v."""
     r, c = w.shape
     n_clients = grads.shape[0]
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     cw = jnp.asarray(cweights, jnp.float32)
     scal = jnp.stack([jnp.asarray(inv, jnp.float32),
                       jnp.asarray(eta, jnp.float32)])
@@ -214,9 +205,7 @@ def fedsgd_aggregate_weighted(w, grads, cweights, inv, eta, *,
     return pl.pallas_call(
         _fedsgd_aggregate_weighted_kernel,
         grid=(r // br,),
-        in_specs=[spec, gspec,
-                  pl.BlockSpec(memory_space=pl.MemorySpace.ANY),
-                  pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[spec, gspec, _SMEM, _SMEM],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((r, c), w.dtype),
                    jax.ShapeDtypeStruct((r, c), jnp.float32),
@@ -271,64 +260,57 @@ def client_rank_sort(grads, cweights, *, block_rows: int = 256,
     clients last — the shared first stage of `coord_median` and
     `trimmed_mean` (kernels/ops.packed_robust_aggregate)."""
     c_clients, r, c = grads.shape
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     cw = jnp.asarray(cweights, jnp.float32)
     gspec = pl.BlockSpec((c_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _client_rank_sort_kernel,
         grid=(r // br,),
-        in_specs=[gspec, pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[gspec, _SMEM],
         out_specs=gspec,
         out_shape=jax.ShapeDtypeStruct((c_clients, r, c), jnp.float32),
         interpret=interpret,
     )(grads, cw)
 
 
-def _exponent_histogram_kernel(q_ref, pr_ref, hist_ref, acc_ref):
+def _exponent_histogram_kernel(q_ref, pr_ref, hist_ref):
     """256-bin histogram over the exponent byte of non-negative fp32 q.
 
-    Per grid block: bin counts accumulate in the VMEM scratch `acc_ref`
-    (laid out (2, 128) so the bin axis tiles the VPU lanes), built by a
-    compare-against-bin-iota reduction over row chunks — no scatter-add,
-    which XLA:CPU serializes at ~130 ns/element and which TPU lowers
-    poorly for int32. Grid steps are sequential on TPU, so the running
-    total in `hist_ref` (same output block every step) is race-free."""
-    rows = q_ref.shape[0]
+    Counts are kept per (bin, lane): row r of the block adds the one-hot
+    compare ``bins == byte[r]`` — the (1, L) row broadcast down the 256
+    bin sublanes — so the kernel needs no scatter-add (which XLA:CPU
+    serializes at ~130 ns/element and TPU lowers poorly for int32), no
+    reshape and no cross-lane reduction. Invalid coordinates carry byte
+    -1, which matches no bin; everything stays int32, because Mosaic
+    cannot relayout the bool masks a flattening reshape would need. Grid
+    steps run in order ("arbitrary"), so the running total in `hist_ref`
+    (same output block every step) is race-free; the caller sums the
+    lanes."""
+    rows, width = q_ref.shape
     chunk = min(rows, 8)
     while rows % chunk:
         chunk -= 1
-    # bins as a 2D iota (TPU requires >= 2D); bin id = 128*sub + lane
-    bins = jax.lax.broadcasted_iota(jnp.int32, (256, 1), 0)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (256, width), 0)
 
-    acc_ref[...] = jnp.zeros((2, 128), jnp.int32)
+    def body(c, acc):
+        rs = pl.ds(c * chunk, chunk)
+        bits = jax.lax.bitcast_convert_type(
+            q_ref[rs, :].astype(jnp.float32), jnp.int32)
+        byte = jnp.where(pr_ref[rs, :] > 0, bits >> 23, -1)
+        for r in range(chunk):
+            acc = acc + jnp.where(bins == byte[r:r + 1, :], 1, 0)
+        return acc
 
-    def body(c, carry):
-        q = q_ref[pl.ds(c * chunk, chunk), :].astype(jnp.float32)
-        valid = pr_ref[pl.ds(c * chunk, chunk), :] > 0
-        byte = jax.lax.bitcast_convert_type(q, jnp.int32) >> 23
-        flat = byte.reshape(1, -1)
-        ones = jnp.where(valid.reshape(1, -1), 1, 0)
-        acc_ref[...] += jnp.sum(jnp.where(flat == bins, ones, 0),
-                                axis=1).reshape(2, 128)
-        return carry
+    acc = jax.lax.fori_loop(0, rows // chunk, body,
+                            jnp.zeros((256, width), jnp.int32))
 
-    jax.lax.fori_loop(0, rows // chunk, body, 0)
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        hist_ref[...] = acc_ref[...]
+        hist_ref[...] = acc
 
-    @pl.when(i > 0)
+    @pl.when(pl.program_id(0) > 0)
     def _accum():
-        hist_ref[...] += acc_ref[...]
+        hist_ref[...] += acc
 
 
 def exponent_histogram(q, prunable, *, block_rows: int = 256,
@@ -341,24 +323,19 @@ def exponent_histogram(q, prunable, *, block_rows: int = 256,
     (core/round_engine.py), whose cumulative sum pins the top 8 bits of
     the k-th smallest importance in one data scan."""
     r, c = q.shape
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     hist = pl.pallas_call(
         _exponent_histogram_kernel,
         grid=(r // br,),
         in_specs=[spec, spec],
-        out_specs=pl.BlockSpec((2, 128), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, 128), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((2, 128), jnp.int32)],
+        out_specs=pl.BlockSpec((256, c), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((256, c), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(q, prunable)
-    return hist.reshape(256)
+    return hist.sum(axis=1)
 
 
 def _masked_update_kernel(w_ref, g_ref, m_ref, eta_ref, o_ref):
@@ -372,20 +349,13 @@ def masked_update_2d(w, g, mask, eta, *, block_rows: int = 256,
                      interpret: bool | None = None):
     """Fused (w - eta g) * mask on [R, 128*k] tiles."""
     r, c = w.shape
-    if c % LANES:
-        raise ValueError(f"last dim must be a multiple of {LANES}")
-    br = min(block_rows, r)
-    if r % br:
-        raise ValueError(f"rows {r} must divide block {br}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    br, interpret = _row_blocks(r, c, block_rows, interpret)
     eta_arr = jnp.asarray([eta], jnp.float32)
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     return pl.pallas_call(
         _masked_update_kernel,
         grid=(r // br,),
-        in_specs=[spec, spec, spec,
-                  pl.BlockSpec(memory_space=pl.MemorySpace.ANY)],
+        in_specs=[spec, spec, spec, _SMEM],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r, c), w.dtype),
         interpret=interpret,

@@ -223,6 +223,32 @@ def test_exponent_histogram_kernel_matches_xla(env):
     assert bool(jnp.all(t_ref == t_pal))
 
 
+def test_exponent_histogram_kernel_rejects_unpacked_shape():
+    """The Pallas histogram raises on a shape it cannot tile instead of
+    quietly switching to the scatter mirror."""
+    q = jnp.ones((4, 100), jnp.float32)
+    with pytest.raises(ValueError, match="packed"):
+        ops.packed_exponent_histogram(q, q, impl="pallas")
+    assert int(ops.packed_exponent_histogram(q, q, impl="xla").sum()) == 400
+
+
+@pytest.mark.parametrize("clients", [1, 8, 32, 64, 128, 1024])
+def test_packed_block_rows_bound_the_client_slab(clients):
+    """Row blocks shrink as the client stack grows, so a double-buffered
+    [C, block, 128] fp32 slab stays within its VMEM share; blocks are
+    multiples of 8 that divide the packed rows, never above 256."""
+    rows = 2304
+    for budget in (ops._STACK_BYTES, ops._SORT_STACK_BYTES):
+        br = ops._packed_block_rows(rows, clients, budget)
+        assert br % 8 == 0 and rows % br == 0 and 8 <= br <= 256
+        assert clients * br * 128 * 4 <= budget or br == 8
+        if br < 256:   # the next larger block would not fit
+            assert clients * 2 * br * 128 * 4 > budget or rows % (2 * br)
+    assert ops._packed_block_rows(rows, 64) == 128
+    assert ops._packed_block_rows(rows, 64, ops._SORT_STACK_BYTES) == 32
+    assert ops._packed_block_rows(12) == 12     # full-extent block
+
+
 # -- bucketed client axis: ragged batches + varying selection ----------------
 
 
