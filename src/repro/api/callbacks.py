@@ -37,6 +37,7 @@ import dataclasses
 import json
 from typing import Sequence
 
+from repro import obs
 from repro.checkpoint import CheckpointManager
 from repro.core.federated import RoundMetrics
 
@@ -123,7 +124,8 @@ def save_trainer_state(
         "fault_counters": dict(getattr(trainer, "fault_counters", {})),
         "agg_counters": dict(getattr(trainer, "agg_counters", {})),
     }
-    return manager.save(int(m.round), tree, extra=extra)
+    with obs.span("checkpoint.save"):
+        return manager.save(int(m.round), tree, extra=extra)
 
 
 def restore_trainer_state(

@@ -27,6 +27,7 @@ from typing import Any, Callable, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.api.callbacks import (
     Callback, CheckpointCallback, metrics_from_dict, metrics_to_dict,
     restore_trainer_state,
@@ -397,7 +398,8 @@ class Experiment:
         aggregator = make_aggregator(sc.aggregator, **sc.aggregator_kwargs)
         agg_key = (aggregator.spec_key if aggregator is not None else "mean")
         local = LOCAL_SCHEMES.get(sc.local_scheme)(sc)
-        params = env.init_fn(jax.random.key(spec.run.seed))
+        with obs.span("experiment.init"):
+            params = env.init_fn(jax.random.key(spec.run.seed))
         if trainer is not None:
             bad = [name for name, a, b in (
                 ("scheme.eta", trainer.eta, sc.eta),

@@ -24,6 +24,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import obs
+
 PyTree = Any
 
 
@@ -39,6 +41,8 @@ def _path_dict(tree: PyTree) -> dict[str, np.ndarray]:
     out = {}
     for kp, leaf in flat:
         key = jax.tree_util.keystr(kp)
+        if isinstance(leaf, jax.Array):
+            obs.count("d2h")
         out[key] = np.asarray(leaf)
     return out
 

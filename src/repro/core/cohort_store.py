@@ -43,12 +43,12 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.round_engine import bucket_capacity
 
 
@@ -180,9 +180,9 @@ class CohortStore:
         if i not in self._live:
             self._launch(i)                 # miss: first block, or no prefetch
             th, box = self._pending.pop(i)
-            t0 = time.perf_counter()
-            th.join()
-            self.counters["prefetch_stall_s"] += time.perf_counter() - t0
+            with obs.span("cohort.wait") as wait:
+                th.join()
+            self.counters["prefetch_stall_s"] += wait.t1 - wait.t0
             err = box.get("error")
             if err is not None:
                 raise err

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import pruning
 from repro.core.client_store import (ClientStore, StoreBudgetError,
                                      estimated_store_nbytes)
@@ -101,6 +102,16 @@ def _default_device_budget() -> int:
     edge-scale config in the repo keeps today's replicated store."""
     env = os.environ.get("REPRO_DEVICE_MEM_BUDGET")
     return int(env) if env else 1 << 30
+
+
+def _all_finite(tree) -> bool:
+    """Whether every leaf of `tree` is finite: one device->host read per
+    leaf, up to the first that is not."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        obs.count("d2h")
+        if not bool(jnp.all(jnp.isfinite(leaf))):
+            return False
+    return True
 
 
 @dataclasses.dataclass
@@ -293,7 +304,8 @@ class FederatedTrainer:
     def params(self) -> PyTree:
         if self.backend == "packed":
             if self._w_view is None or self._w_view[0] is not self._w:
-                self._w_view = (self._w, self.pack.unpack(self._w))
+                with obs.span("trainer.unpack"):
+                    self._w_view = (self._w, self.pack.unpack(self._w))
             return self._w_view[1]
         return self._params
 
@@ -309,7 +321,8 @@ class FederatedTrainer:
     def global_grad(self) -> PyTree:
         if self.backend == "packed":
             if self._v_view is None or self._v_view[0] is not self._v:
-                self._v_view = (self._v, self.pack.unpack(self._v))
+                with obs.span("trainer.unpack"):
+                    self._v_view = (self._v, self.pack.unpack(self._v))
             return self._v_view[1]
         return self._global_grad
 
@@ -333,37 +346,39 @@ class FederatedTrainer:
         the global gradient, the batch RNG, and every counter are reset
         exactly as the constructor would, so a reused trainer's trajectory
         is bit-for-bit a cold one's."""
-        self.rng = np.random.default_rng(seed)
-        self.channel_noise = channel_noise
-        self.fault_model = fault_model
-        self.fault_counters = {"n_dropped": 0, "n_quarantined": 0,
-                               "n_skipped_rounds": 0, "n_corrupt_finite": 0}
-        self.agg_counters = ({self.aggregator.stat_field: 0}
-                             if self.aggregator is not None else {})
-        self.n_fallback_rounds = 0
-        self.n_batch_uploads = 0
-        self.n_block_dispatches = 0
-        self._callbacks = ()
-        # zero the fleet counters IN PLACE: a run's CohortStore accumulates
-        # into this dict by reference
-        self.fleet_counters.update(fleet_counters_zero())
-        self.streaming = False
-        self._cohorts = None
-        # per-client optimizer state MUST NOT survive pooling: a reused
-        # trainer carrying the previous cell's FedDyn correction buffer
-        # would silently bias the next run (the regression test in
-        # tests/test_local_schemes.py pins pooled == cold byte-identical).
-        # Dropping the buffer (rather than zeroing in place) also frees
-        # the device memory until the next stateful run touches it.
-        self._h = None
-        if self.engine is not None:
-            self.engine.last_h = None
-        if self.backend == "packed":
-            self._w, self._v = self.engine.init_buffers(params)
-            self._w_view = self._v_view = None
-        else:
-            self._params = params
-            self._global_grad = jax.tree.map(jnp.zeros_like, params)
+        with obs.span("trainer.reset"):
+            self.rng = np.random.default_rng(seed)
+            self.channel_noise = channel_noise
+            self.fault_model = fault_model
+            self.fault_counters = {"n_dropped": 0, "n_quarantined": 0,
+                                   "n_skipped_rounds": 0,
+                                   "n_corrupt_finite": 0}
+            self.agg_counters = ({self.aggregator.stat_field: 0}
+                                 if self.aggregator is not None else {})
+            self.n_fallback_rounds = 0
+            self.n_batch_uploads = 0
+            self.n_block_dispatches = 0
+            self._callbacks = ()
+            # zero the fleet counters IN PLACE: a run's CohortStore accumulates
+            # into this dict by reference
+            self.fleet_counters.update(fleet_counters_zero())
+            self.streaming = False
+            self._cohorts = None
+            # per-client optimizer state MUST NOT survive pooling: a reused
+            # trainer carrying the previous cell's FedDyn correction buffer
+            # would silently bias the next run (the regression test in
+            # tests/test_local_schemes.py pins pooled == cold byte-identical).
+            # Dropping the buffer (rather than zeroing in place) also frees
+            # the device memory until the next stateful run touches it.
+            self._h = None
+            if self.engine is not None:
+                self.engine.last_h = None
+            if self.backend == "packed":
+                self._w, self._v = self.engine.init_buffers(params)
+                self._w_view = self._v_view = None
+            else:
+                self._params = params
+                self._global_grad = jax.tree.map(jnp.zeros_like, params)
 
     # -- noisy aggregation channel ------------------------------------------
 
@@ -491,6 +506,7 @@ class FederatedTrainer:
             # computes, so the two backends stay bit-for-bit comparable
             loss, grads = self._wgrad_fn(pruned, x, y, jnp.asarray(sw))
         grads = pruning.apply_masks(grads, masks)  # pruned coords not uploaded
+        obs.count("d2h")
         return grads, masks, float(loss)
 
     def _client_update_local(self, n: int, lam: float, batches: list,
@@ -531,6 +547,7 @@ class FederatedTrainer:
             else:
                 loss, g = self._wgrad_fn(u, x, y, jnp.asarray(sw))
             if t == 0:
+                obs.count("d2h")
                 loss0 = float(loss)
             g = pruning.apply_masks(g, masks)
             if ls.name == "fedavg":
@@ -625,8 +642,7 @@ class FederatedTrainer:
                 # `g + 0.0` normalization of -0.0 then matches bitwise
                 pz = self._noise_layout().unpack(jnp.asarray(po[j]))
                 g = jax.tree.map(lambda t, z: t + z, g, pz)
-            if all(bool(jnp.all(jnp.isfinite(leaf)))
-                   for leaf in jax.tree_util.tree_leaves(g)):
+            if _all_finite(g):
                 grads.append(g)
                 if dyn:
                     # the state only moves for arrived-AND-finite uploads
@@ -688,7 +704,7 @@ class FederatedTrainer:
                 gp = gp * jnp.float32(cf[j])
             if po is not None:
                 gp = gp + jnp.asarray(po[j])
-            fin = bool(jnp.all(jnp.isfinite(gp)))
+            fin = _all_finite(gp)
             gps.append(gp)
             cws.append(1.0 if (ok[j] and fin) else 0.0)
         c_b = bucket_capacity(len(selected),
@@ -908,68 +924,74 @@ class FederatedTrainer:
         `choice` calls — same order, same arguments — that the per-round
         path's _sample_batch would make, so the batch sequence is
         bit-for-bit the reference one."""
-        sels = [infos[start + k][0] for k in range(n_rounds)]
-        cids, counts = self._block_cids(start, n_rounds, infos)
-        c_max = int(counts.max())
-        blen = self._block_key(sels[0], infos[start][1])[2]
-        # multi-step schemes draw an E-deep index stack per (round, client)
-        # — same RNG calls, same round -> client -> step order as the
-        # per-round path, so the batch stream stays bit-for-bit shared
-        ls = self.local_scheme
-        if ls is None:
-            idxs = np.empty((n_rounds, c_max, blen), np.int32)
-            sw = np.ones((n_rounds, c_max, blen), np.float32)
-        else:
-            idxs = np.empty((n_rounds, c_max, ls.steps, blen), np.int32)
-            sw = np.ones((n_rounds, c_max, ls.steps, blen), np.float32)
-        lams = np.empty((n_rounds, c_max), np.float64)
-        # host-drawn fault masks join the stacked [K, C] schedule operands
-        # (ones = clean defaults, exact no-ops on device) whenever a fault
-        # model is active — one upload per block, zero per-round H2D
-        fault_on = self.fault_model is not None
-        pos = None
-        if fault_on:
-            fw = np.ones((n_rounds, c_max), np.float32)
-            cfa = np.ones((n_rounds, c_max), np.float32)
-            # the additive-poison stack is built lazily: zero until some
-            # round in the block actually flagged a byzantine client, so
-            # clean blocks never allocate the [K, C, R, L] operand
-            if any(infos[start + k][6] is not None
-                   and infos[start + k][6].poison is not None
-                   for k in range(n_rounds)):
-                pack = self._noise_layout()
-                pos = np.zeros((n_rounds, c_max, pack.rows, LANES),
-                               np.float32)
-        any_ragged = False
-        for k, sel in enumerate(sels):
-            lam_s = infos[start + k][1]
+        with obs.span("trainer.draw"):
+            sels = [infos[start + k][0] for k in range(n_rounds)]
+            cids, counts = self._block_cids(start, n_rounds, infos)
+            c_max = int(counts.max())
+            blen = self._block_key(sels[0], infos[start][1])[2]
+            # multi-step schemes draw an E-deep index stack per (round,
+            # client) — same RNG calls, same round -> client -> step order
+            # as the per-round path, so the batch stream stays bit-for-bit
+            # shared
+            ls = self.local_scheme
+            if ls is None:
+                idxs = np.empty((n_rounds, c_max, blen), np.int32)
+                sw = np.ones((n_rounds, c_max, blen), np.float32)
+            else:
+                idxs = np.empty((n_rounds, c_max, ls.steps, blen),
+                                np.int32)
+                sw = np.ones((n_rounds, c_max, ls.steps, blen),
+                             np.float32)
+            lams = np.empty((n_rounds, c_max), np.float64)
+            # host-drawn fault masks join the stacked [K, C] schedule
+            # operands (ones = clean defaults, exact no-ops on device)
+            # whenever a fault model is active — one upload per block, zero
+            # per-round H2D
+            fault_on = self.fault_model is not None
+            pos = None
             if fault_on:
-                fault = infos[start + k][6]
-                if fault is not None:
-                    fw[k, :len(sel)] = np.asarray(fault.upload_ok,
-                                                  np.float32)
-                    if fault.corrupt is not None:
-                        cfa[k, :len(sel)] = fault.corrupt
-                    if pos is not None and fault.poison is not None:
-                        pos[k, :len(sel)] = self._poison_stack(fault)
-            for j, n in enumerate(sel):
-                lams[k, j] = lam_s[n]
-                for t in range(1 if ls is None else ls.steps):
-                    row = idxs[k, j] if ls is None else idxs[k, j, t]
-                    swr = sw[k, j] if ls is None else sw[k, j, t]
-                    draw = self._draw_indices(self._client_len(n))
-                    m = len(draw)
-                    if m < blen:         # ragged: repeat last drawn sample
-                        row[:m] = draw              # with weight 0, exactly
-                        row[m:] = draw[-1]          # like _sample_batch
-                        swr[m:] = 0.0
-                        any_ragged = True
-                    else:
-                        row[:] = draw
-            c_k = len(sel)               # pad rows to c_max by replicating
-            idxs[k, c_k:] = idxs[k, c_k - 1]     # the round's last client
-            sw[k, c_k:] = sw[k, c_k - 1]         # (cids padded identically
-            lams[k, c_k:] = lam_s[sel[-1]]       # by _block_cids)
+                fw = np.ones((n_rounds, c_max), np.float32)
+                cfa = np.ones((n_rounds, c_max), np.float32)
+                # the additive-poison stack is built lazily: zero until
+                # some round in the block actually flagged a byzantine
+                # client, so clean blocks never allocate the [K, C, R, L]
+                # operand
+                if any(infos[start + k][6] is not None
+                       and infos[start + k][6].poison is not None
+                       for k in range(n_rounds)):
+                    pack = self._noise_layout()
+                    pos = np.zeros((n_rounds, c_max, pack.rows, LANES),
+                                   np.float32)
+            any_ragged = False
+            for k, sel in enumerate(sels):
+                lam_s = infos[start + k][1]
+                if fault_on:
+                    fault = infos[start + k][6]
+                    if fault is not None:
+                        fw[k, :len(sel)] = np.asarray(fault.upload_ok,
+                                                      np.float32)
+                        if fault.corrupt is not None:
+                            cfa[k, :len(sel)] = fault.corrupt
+                        if pos is not None and fault.poison is not None:
+                            pos[k, :len(sel)] = self._poison_stack(fault)
+                for j, n in enumerate(sel):
+                    lams[k, j] = lam_s[n]
+                    for t in range(1 if ls is None else ls.steps):
+                        row = idxs[k, j] if ls is None else idxs[k, j, t]
+                        swr = sw[k, j] if ls is None else sw[k, j, t]
+                        draw = self._draw_indices(self._client_len(n))
+                        m = len(draw)
+                        if m < blen:     # ragged: repeat last drawn sample
+                            row[:m] = draw          # with weight 0, exactly
+                            row[m:] = draw[-1]      # like _sample_batch
+                            swr[m:] = 0.0
+                            any_ragged = True
+                        else:
+                            row[:] = draw
+                c_k = len(sel)           # pad rows to c_max by replicating
+                idxs[k, c_k:] = idxs[k, c_k - 1]  # the round's last client
+                sw[k, c_k:] = sw[k, c_k - 1]      # (cids padded identically
+                lams[k, c_k:] = lam_s[sel[-1]]    # by _block_cids)
         dyn = ls is not None and ls.stateful
         slab_ids = None
         h_arg = None
@@ -1015,9 +1037,12 @@ class FederatedTrainer:
         asts = (self.engine.last_agg_stat    # [K] lazy reducer diagnostics
                 if self.aggregator is not None else None)
         self.n_block_dispatches += 1
-        for k in range(n_rounds):
-            out[start + k] = (losses[k, : int(counts[k])], n_oks[k],
-                              asts[k] if asts is not None else None)
+        # slicing a value the device has not finished waits for it: this
+        # span holds most of the host's wait for a block
+        with obs.span("trainer.slice"):
+            for k in range(n_rounds):
+                out[start + k] = (losses[k, : int(counts[k])], n_oks[k],
+                                  asts[k] if asts is not None else None)
         # fires right after the dispatch returns: the block's losses are
         # still lazy device arrays, so hooks here never force a sync
         for cb in self._callbacks:
@@ -1093,131 +1118,152 @@ class FederatedTrainer:
         pending: list[tuple[RoundMetrics, Any, Any, Any, Any]] = []
 
         def materialize():
-            for m, losses, n_ok, fault, ast in pending:
-                mask = (np.asarray(fault.upload_ok, bool)
-                        if fault is not None else None)
-                if losses is not None:
-                    # float64 mean over the synced fp32 values — identical
-                    # to the old eager np.mean over a list of floats;
-                    # restricted to the uploads that arrived (the server
-                    # never observes a dropped client's loss)
-                    arr = np.asarray(losses, np.float64)
-                    if mask is not None:
-                        arr = arr[mask]
-                    m.train_loss = float(arr.mean()) if arr.size else float("nan")
-                n_sel = len(m.selected)
-                n_up = int(mask.sum()) if mask is not None else n_sel
-                m.n_faulted = n_sel - n_up
-                if n_ok is not None:
-                    ok = int(n_ok)
-                    # on the robust path the quarantine count folds the
-                    # reducer's survivor arithmetic the same way: n_ok is
-                    # still "weighted clients whose upload stayed finite"
-                    m.n_quarantined = max(0, n_up - ok)
-                    if n_sel and ok == 0:
-                        self.fault_counters["n_skipped_rounds"] += 1
-                self.fault_counters["n_dropped"] += m.n_faulted
-                self.fault_counters["n_quarantined"] += m.n_quarantined
-                # corrupt-but-FINITE arrivals: damage the isfinite guard
-                # cannot see (satellite of the quarantine's documented
-                # blind spot) — counted host-side from the draw so reports
-                # stop under-counting corruption. `.get` keeps restores of
-                # pre-PR-7 checkpoints (no such key) working.
-                if fault is not None:
-                    ncf = 0
-                    arrived = (mask if mask is not None
-                               else np.ones(n_sel, bool))
-                    if fault.corrupt is not None:
-                        cfv = np.asarray(fault.corrupt, np.float64)
-                        ncf += int((arrived & np.isfinite(cfv)
-                                    & (cfv != 1.0)).sum())
-                    flags = getattr(fault.poison, "flags", None)
-                    if flags is not None:
-                        ncf += int((arrived & np.asarray(flags, bool)).sum())
-                    self.fault_counters["n_corrupt_finite"] = (
-                        self.fault_counters.get("n_corrupt_finite", 0) + ncf)
-                if ast is not None and self.aggregator is not None:
-                    m.n_agg_adjusted = int(ast)
-                    sf = self.aggregator.stat_field
-                    self.agg_counters[sf] = (self.agg_counters.get(sf, 0)
-                                             + m.n_agg_adjusted)
-                for cb in callbacks:
-                    cb.on_round_end(m, self)
+            if not pending:
+                return
+            with obs.span("trainer.materialize") as sp:
+                # wait for the device before the first read (which would block
+                # on it anyway), so the wait and the reads are timed apart
+                with obs.span("trainer.wait"):
+                    jax.block_until_ready([
+                        x for p in pending for x in p[1:]
+                        if isinstance(x, jax.Array)])
+                for m, losses, n_ok, fault, ast in pending:
+                    mask = (np.asarray(fault.upload_ok, bool)
+                            if fault is not None else None)
+                    if losses is not None:
+                        # float64 mean over the synced fp32 values —
+                        # identical to the old eager np.mean over a list of
+                        # floats; restricted to the uploads that arrived
+                        # (the server never observes a dropped client's
+                        # loss)
+                        if isinstance(losses, jax.Array):
+                            sp.count("d2h")
+                        arr = np.asarray(losses, np.float64)
+                        if mask is not None:
+                            arr = arr[mask]
+                        m.train_loss = (float(arr.mean()) if arr.size
+                                        else float("nan"))
+                    n_sel = len(m.selected)
+                    n_up = int(mask.sum()) if mask is not None else n_sel
+                    m.n_faulted = n_sel - n_up
+                    if n_ok is not None:
+                        if isinstance(n_ok, jax.Array):
+                            sp.count("d2h")
+                        ok = int(n_ok)
+                        # on the robust path the quarantine count folds the
+                        # reducer's survivor arithmetic the same way: n_ok is
+                        # still "weighted clients whose upload stayed finite"
+                        m.n_quarantined = max(0, n_up - ok)
+                        if n_sel and ok == 0:
+                            self.fault_counters["n_skipped_rounds"] += 1
+                    self.fault_counters["n_dropped"] += m.n_faulted
+                    self.fault_counters["n_quarantined"] += m.n_quarantined
+                    # corrupt-but-FINITE arrivals: damage the isfinite
+                    # guard cannot see (satellite of the quarantine's
+                    # documented blind spot) — counted host-side from the
+                    # draw so reports stop under-counting corruption. `.get`
+                    # keeps restores of pre-PR-7 checkpoints (no such key)
+                    # working.
+                    if fault is not None:
+                        ncf = 0
+                        arrived = (mask if mask is not None
+                                   else np.ones(n_sel, bool))
+                        if fault.corrupt is not None:
+                            cfv = np.asarray(fault.corrupt, np.float64)
+                            ncf += int((arrived & np.isfinite(cfv)
+                                        & (cfv != 1.0)).sum())
+                        flags = getattr(fault.poison, "flags", None)
+                        if flags is not None:
+                            ncf += int((arrived
+                                        & np.asarray(flags, bool)).sum())
+                        self.fault_counters["n_corrupt_finite"] = (
+                            self.fault_counters.get("n_corrupt_finite", 0)
+                            + ncf)
+                    if ast is not None and self.aggregator is not None:
+                        if isinstance(ast, jax.Array):
+                            sp.count("d2h")
+                        m.n_agg_adjusted = int(ast)
+                        sf = self.aggregator.stat_field
+                        self.agg_counters[sf] = (self.agg_counters.get(sf, 0)
+                                                 + m.n_agg_adjusted)
+                    for cb in callbacks:
+                        cb.on_round_end(m, self)
             pending.clear()
 
-        n_rounds = schedule.a.shape[0]
-        # Per-round host bookkeeping is schedule-pure (independent of
-        # training state), so compute it — and the stop-condition
-        # truncation — up front; the block partition then only has to
-        # respect eval boundaries.
-        infos = []
-        cum_t = cum_e = 0.0
-        for s in range(n_rounds):
-            a_s, lam_s = schedule.a[s], schedule.lam[s]
-            p_s, f_s = schedule.power[s], schedule.freq[s]
-            selected = [int(i) for i in np.flatnonzero(a_s > 0)]
-            # per-client tau_n + tau^_n feed both the round deadline (the
-            # gated max is round_delay's expression verbatim — bitwise
-            # identical bookkeeping) and the straggler fault model's
-            # judgment against that deadline
-            per = per_client_delay(lam_s, p_s, f_s, h_up, h_down, sp)
-            gated = np.asarray(a_s, np.float64) * per
-            d = float(gated.max()) if gated.size else 0.0
-            e = round_energy(a_s, lam_s, p_s, f_s, h_up, h_down, sp)
-            cum_t += d
-            cum_e += e
-            fault = None
-            if self.fault_model is not None and selected:
-                sel_arr = np.asarray(selected, int)
-                fault = self.fault_model.draw(
-                    s, len(self.clients), sel_arr,
-                    delays=per[sel_arr], deadline=d)
-            infos.append((selected, lam_s, d, e, cum_t, cum_e, fault))
-            if stop_delay is not None and cum_t >= stop_delay:
-                break
-            if stop_energy is not None and cum_e >= stop_energy:
-                break
+        with obs.span("trainer.plan"):
+            n_rounds = schedule.a.shape[0]
+            # Per-round host bookkeeping is schedule-pure (independent of
+            # training state), so compute it — and the stop-condition
+            # truncation — up front; the block partition then only has to
+            # respect eval boundaries.
+            infos = []
+            cum_t = cum_e = 0.0
+            for s in range(n_rounds):
+                a_s, lam_s = schedule.a[s], schedule.lam[s]
+                p_s, f_s = schedule.power[s], schedule.freq[s]
+                selected = [int(i) for i in np.flatnonzero(a_s > 0)]
+                # per-client tau_n + tau^_n feed both the round deadline
+                # (the gated max is round_delay's expression verbatim —
+                # bitwise identical bookkeeping) and the straggler fault
+                # model's judgment against that deadline
+                per = per_client_delay(lam_s, p_s, f_s, h_up, h_down, sp)
+                gated = np.asarray(a_s, np.float64) * per
+                d = float(gated.max()) if gated.size else 0.0
+                e = round_energy(a_s, lam_s, p_s, f_s, h_up, h_down, sp)
+                cum_t += d
+                cum_e += e
+                fault = None
+                if self.fault_model is not None and selected:
+                    sel_arr = np.asarray(selected, int)
+                    fault = self.fault_model.draw(
+                        s, len(self.clients), sel_arr,
+                        delays=per[sel_arr], deadline=d)
+                infos.append((selected, lam_s, d, e, cum_t, cum_e, fault))
+                if stop_delay is not None and cum_t >= stop_delay:
+                    break
+                if stop_energy is not None and cum_e >= stop_energy:
+                    break
 
-        # Checkpoint rounds (repro.api.Callback.checkpoint_every): these
-        # become materialization points and block boundaries so the hook
-        # observes state coherent at exactly that round.
-        def _ckpt_cbs(s: int) -> list:
-            return [cb for cb in callbacks
-                    if getattr(cb, "checkpoint_every", None)
-                    and s % cb.checkpoint_every == 0]
+            # Checkpoint rounds (repro.api.Callback.checkpoint_every):
+            # these become materialization points and block boundaries so
+            # the hook observes state coherent at exactly that round.
+            def _ckpt_cbs(s: int) -> list:
+                return [cb for cb in callbacks
+                        if getattr(cb, "checkpoint_every", None)
+                        and s % cb.checkpoint_every == 0]
 
-        ckpt_rounds = {s for s in range(start_round, len(infos))
-                       if _ckpt_cbs(s)}
+            ckpt_rounds = {s for s in range(start_round, len(infos))
+                           if _ckpt_cbs(s)}
 
-        blocks: dict[int, int] = {}
-        if self.rounds_per_dispatch > 1 and self.backend == "packed":
-            boundaries = set(ckpt_rounds)
-            if eval_fn is not None:
-                boundaries |= {s for s in range(len(infos))
-                               if s % eval_every == 0}
-                boundaries.add(n_rounds - 1)
-            blocks = self._plan_blocks(infos, boundaries,
-                                       self.rounds_per_dispatch,
-                                       first_round=start_round)
+            blocks: dict[int, int] = {}
+            if self.rounds_per_dispatch > 1 and self.backend == "packed":
+                boundaries = set(ckpt_rounds)
+                if eval_fn is not None:
+                    boundaries |= {s for s in range(len(infos))
+                                   if s % eval_every == 0}
+                    boundaries.add(n_rounds - 1)
+                blocks = self._plan_blocks(infos, boundaries,
+                                           self.rounds_per_dispatch,
+                                           first_round=start_round)
 
-        self.streaming = False
-        self._cohorts = None
-        if blocks and self.store_mode() == "streamed":
-            # cohort plans are a pure function of the block partition
-            # (selection-only, no RNG), so a resumed run — same infos, same
-            # first_round — replays the identical cohort schedule bit for
-            # bit; prefetch of the first two cohorts starts here, before
-            # any round executes
-            self._cohorts = CohortStore(
-                self.clients, mesh=self.engine.mesh,
-                shards=self.engine.shards,
-                bucket_size=self.engine.bucket_size,
-                max_clients=len(self.clients),
-                counters=self.fleet_counters)
-            self._cohorts.schedule(
-                [(st, *self._block_cids(st, blocks[st], infos))
-                 for st in sorted(blocks)])
-            self.streaming = True
+            self.streaming = False
+            self._cohorts = None
+            if blocks and self.store_mode() == "streamed":
+                # cohort plans are a pure function of the block partition
+                # (selection-only, no RNG), so a resumed run — same infos,
+                # same first_round — replays the identical cohort schedule
+                # bit for bit; prefetch of the first two cohorts starts
+                # here, before any round executes
+                self._cohorts = CohortStore(
+                    self.clients, mesh=self.engine.mesh,
+                    shards=self.engine.shards,
+                    bucket_size=self.engine.bucket_size,
+                    max_clients=len(self.clients),
+                    counters=self.fleet_counters)
+                self._cohorts.schedule(
+                    [(st, *self._block_cids(st, blocks[st], infos))
+                     for st in sorted(blocks)])
+                self.streaming = True
 
         block_losses: dict[int, Any] = {}
         try:
@@ -1230,8 +1276,9 @@ class FederatedTrainer:
                 if s in block_losses:
                     losses, n_ok, ast = block_losses.pop(s)
                 elif selected:
-                    losses, n_ok, ast = self._round(selected, lam_s, s=s,
-                                                    fault=fault)
+                    with obs.span("trainer.round"):
+                        losses, n_ok, ast = self._round(selected, lam_s,
+                                                        s=s, fault=fault)
                 else:
                     losses = n_ok = ast = None
                 m = RoundMetrics(

@@ -14,6 +14,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.convergence import BoundConstants, theta, theta_decomposition
 from repro.core.ratio import solve_pruning_ratios
 from repro.core.resource import solve_schedule_resources
@@ -124,32 +125,38 @@ def solve_p1(
             f = np.broadcast_to(sp.f_max, f.shape).copy()
         return p, f
 
+    def resources(a, lam):
+        with obs.span("ao.resources"):
+            p, f, _ = solve_schedule_resources(a, lam, e0, t0, h_up, h_down,
+                                               sp)
+        return overrides(p, f)
+
+    def pruning(a, p, f):
+        with obs.span("ao.pruning"):
+            return solve_pruning_ratios(a, p, f, e0, t0, h_up, h_down, sp,
+                                        c)[0]
+
     best: Schedule | None = None
     history = []
     for o in range(cfg.outer_iters):
         # --- (P2): resources given (a, lam)
-        p, f, rinfo = solve_schedule_resources(a, lam, e0, t0, h_up, h_down, sp)
-        p, f = overrides(p, f)
+        p, f = resources(a, lam)
         # --- (P3): pruning ratios given (a, p, f)
         if cfg.fix_lambda is None:
-            lam, linfo = solve_pruning_ratios(a, p, f, e0, t0, h_up, h_down,
-                                              sp, c)
-            p, f, rinfo = solve_schedule_resources(a, lam, e0, t0, h_up,
-                                                   h_down, sp)
-            p, f = overrides(p, f)
+            lam = pruning(a, p, f)
+            p, f = resources(a, lam)
         # --- (P5): selection given (lam, p, f)
         if not cfg.fix_selection:
-            a, sinfo = solve_selection(lam, phi_opt, c, e0, t0, h_up, h_down,
-                                       sp, method=cfg.selection_method,
+            with obs.span("ao.selection"):
+                a, _ = solve_selection(lam, phi_opt, c, e0, t0, h_up,
+                                       h_down, sp,
+                                       method=cfg.selection_method,
                                        coupling=cfg.phi_coupling)
             # selection changed the active set: lambdas/resources for newly
             # selected clients must exist -> one more (P3)+(P2) pass
             if cfg.fix_lambda is None:
-                lam, _ = solve_pruning_ratios(a, p, f, e0, t0, h_up, h_down,
-                                              sp, c)
-            p, f, rinfo = solve_schedule_resources(a, lam, e0, t0, h_up,
-                                                   h_down, sp)
-            p, f = overrides(p, f)
+                lam = pruning(a, p, f)
+            p, f = resources(a, lam)
 
         th = theta(a, lam, phi, c)
         e_tot = total_energy(a, lam, p, f, h_up, h_down, sp)

@@ -554,15 +554,18 @@ class RoundEngine:
         byte-identical), otherwise the local-step body with the FedDyn
         state path unused. FedDyn routes through the dyn round bodies
         instead (extra h/cid operands)."""
-        if self.local_scheme is None:
-            return self._grads_shared(pruned, mask, xs, ys, sw)
-        losses, uploads, _ = self._locals_shared(pruned, mask, xs, ys, sw)
+        with jax.named_scope("round.clients"):
+            if self.local_scheme is None:
+                return self._grads_shared(pruned, mask, xs, ys, sw)
+            losses, uploads, _ = self._locals_shared(pruned, mask, xs, ys,
+                                                     sw)
         return losses, uploads
 
     def _client_grads_multi(self, w, masks, xs, ys, sw):
-        if self.local_scheme is None:
-            return self._grads_multi(w, masks, xs, ys, sw)
-        losses, uploads, _ = self._locals_multi(w, masks, xs, ys, sw)
+        with jax.named_scope("round.clients"):
+            if self.local_scheme is None:
+                return self._grads_multi(w, masks, xs, ys, sw)
+            losses, uploads, _ = self._locals_multi(w, masks, xs, ys, sw)
         return losses, uploads
 
     def _aggregate_update(self, w, v, grads, cw, inv, noise, cf=None,
@@ -601,29 +604,39 @@ class RoundEngine:
         the noisy path goes through the XLA mirror so the fenced mean
         product is materialized before the add (bit-parity with the eager
         reference sequence)."""
-        if cf is not None:
-            grads = grads * cf.astype(jnp.float32)[:, None, None]
-        if poison is not None:
-            grads = grads + poison.astype(jnp.float32)
-        cw_eff, inv_eff, n_ok, alive = ops.packed_client_quarantine(
-            grads, cw, inv)
+        with jax.named_scope("round.aggregate"):
+            if cf is not None:
+                grads = grads * cf.astype(jnp.float32)[:, None, None]
+            if poison is not None:
+                grads = grads + poison.astype(jnp.float32)
+            cw_eff, inv_eff, n_ok, alive = ops.packed_client_quarantine(
+                grads, cw, inv)
         if self.aggregator is not None:
-            ghat, ast = self.aggregator.reduce(grads, cw_eff)
-            w2, g, step = ops.packed_apply_mean_update(
-                w, ghat, jnp.float32(1.0), self.eta, noise=noise)
+            with jax.named_scope("round.aggregate"):
+                ghat, ast = self.aggregator.reduce(grads, cw_eff)
+            with jax.named_scope("round.update"):
+                w2, g, step = ops.packed_apply_mean_update(
+                    w, ghat, jnp.float32(1.0), self.eta, noise=noise)
         elif noise is None:
             ast = jnp.int32(0)
-            w2, g, step = ops.packed_fedsgd_update_weighted(
-                w, grads, cw_eff, inv_eff, self.eta, impl=self.kernel_impl)
+            # one fused kernel sums, averages and steps: its ops carry
+            # the aggregate's scope
+            with jax.named_scope("round.aggregate"):
+                w2, g, step = ops.packed_fedsgd_update_weighted(
+                    w, grads, cw_eff, inv_eff, self.eta,
+                    impl=self.kernel_impl)
         else:
             ast = jnp.int32(0)
-            gsum = ops.packed_weighted_grad_sum(grads, cw_eff)
-            w2, g, step = ops.packed_apply_mean_update(w, gsum, inv_eff,
-                                                       self.eta, noise=noise)
+            with jax.named_scope("round.aggregate"):
+                gsum = ops.packed_weighted_grad_sum(grads, cw_eff)
+            with jax.named_scope("round.update"):
+                w2, g, step = ops.packed_apply_mean_update(
+                    w, gsum, inv_eff, self.eta, noise=noise)
         # all clients faulted: carry params and the broadcast v unchanged
         # (the reference server_step's empty-grads early return)
-        w2 = jnp.where(alive, w2, w)
-        g = jnp.where(alive, g, v)
+        with jax.named_scope("round.update"):
+            w2 = jnp.where(alive, w2, w)
+            g = jnp.where(alive, g, v)
         # cw_eff rides along for the stateful schemes: FedDyn only updates
         # the correction state of clients whose (post-fault) upload arrived
         # finite — exactly the quarantine's surviving weights
@@ -631,15 +644,24 @@ class RoundEngine:
 
     def _threshold_mask(self, w, v, k):
         """Shared-lambda prologue: the round's threshold and keep-mask."""
-        q = (w * v) ** 2
-        thr = kth_smallest_threshold(q, self.prunable, k)
-        _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
-                                             impl=self.kernel_impl)
+        with jax.named_scope("round.threshold"):
+            q = (w * v) ** 2
+            thr = kth_smallest_threshold(q, self.prunable, k)
+        with jax.named_scope("round.masks"):
+            _, mask = ops.packed_importance_mask(w, v, self.prunable, thr,
+                                                 impl=self.kernel_impl)
         return thr, mask
 
     def _thresholds(self, w, v, ks):
         """Per-client-lambda prologue: one threshold per client, [C]."""
-        return kth_smallest_threshold((w * v) ** 2, self.prunable, ks)
+        with jax.named_scope("round.threshold"):
+            return kth_smallest_threshold((w * v) ** 2, self.prunable, ks)
+
+    def _client_masks(self, w, v, prunable, thr):
+        """Per-client-lambda keep-masks [C, R, L], one threshold each."""
+        with jax.named_scope("round.masks"):
+            return ops.packed_importance_masks(w, v, prunable, thr,
+                                               impl=self.kernel_impl)[1]
 
     def _replicated(self, fn, *args):
         """``fn(*args)``, which a mesh runs on every device's full copy of
@@ -669,8 +691,7 @@ class RoundEngine:
                      cf=None, poison=None):
         """One per-client-lambda round (see _round_shared)."""
         thr = self._thresholds(w, v, ks)                       # [C]
-        _, masks = ops.packed_importance_masks(w, v, self.prunable, thr,
-                                               impl=self.kernel_impl)
+        masks = self._client_masks(w, v, self.prunable, thr)
         losses, grads = self._client_grads_multi(w, masks, xs, ys, sw)
         w2, g, step, n_ok, ast, _ = self._aggregate_update(
             w, v, grads, cw, inv, noise, cf, poison)
@@ -705,8 +726,9 @@ class RoundEngine:
                           noise=None, cf=None, poison=None):
         thr, mask = self._threshold_mask(w, v, k)
         pruned = w * mask
-        losses, uploads, hds = self._locals_shared(pruned, mask, xs, ys, sw,
-                                                   h[cid])
+        with jax.named_scope("round.clients"):
+            losses, uploads, hds = self._locals_shared(pruned, mask, xs, ys,
+                                                       sw, h[cid])
         w2, g, step, n_ok, ast, cw_eff = self._aggregate_update(
             w, v, uploads, cw, inv, noise, cf, poison)
         h2 = self._h_scatter(h, cid, hds, cw_eff)
@@ -715,10 +737,10 @@ class RoundEngine:
     def _round_multi_dyn(self, w, v, xs, ys, sw, cw, inv, ks, h, cid,
                          noise=None, cf=None, poison=None):
         thr = self._thresholds(w, v, ks)                       # [C]
-        _, masks = ops.packed_importance_masks(w, v, self.prunable, thr,
-                                               impl=self.kernel_impl)
-        losses, uploads, hds = self._locals_multi(w, masks, xs, ys, sw,
-                                                  h[cid])
+        masks = self._client_masks(w, v, self.prunable, thr)
+        with jax.named_scope("round.clients"):
+            losses, uploads, hds = self._locals_multi(w, masks, xs, ys, sw,
+                                                      h[cid])
         w2, g, step, n_ok, ast, cw_eff = self._aggregate_update(
             w, v, uploads, cw, inv, noise, cf, poison)
         h2 = self._h_scatter(h, cid, hds, cw_eff)
@@ -749,8 +771,9 @@ class RoundEngine:
         hc = h[cid]
 
         def body(pruned_, mask_, xs_, ys_, sw_, hc_):
-            losses, ups, hds = self._locals_shared(pruned_, mask_, xs_, ys_,
-                                                   sw_, hc_)
+            with jax.named_scope("round.clients"):
+                losses, ups, hds = self._locals_shared(pruned_, mask_, xs_,
+                                                       ys_, sw_, hc_)
             ga, hda = jax.lax.all_gather((ups, hds), "data", axis=0,
                                          tiled=True)
             return losses, ga, hda
@@ -773,10 +796,10 @@ class RoundEngine:
         hc = h[cid]
 
         def body(w_, v_, pr, thr_, xs_, ys_, sw_, hc_):
-            _, masks = ops.packed_importance_masks(w_, v_, pr, thr_,
-                                                   impl=self.kernel_impl)
-            losses, ups, hds = self._locals_multi(w_, masks, xs_, ys_, sw_,
-                                                  hc_)
+            masks = self._client_masks(w_, v_, pr, thr_)
+            with jax.named_scope("round.clients"):
+                losses, ups, hds = self._locals_multi(w_, masks, xs_, ys_,
+                                                      sw_, hc_)
             ga, hda = jax.lax.all_gather((ups, hds), "data", axis=0,
                                          tiled=True)
             return losses, ga, hda
@@ -1033,16 +1056,17 @@ class RoundEngine:
         gradient went non-finite, and ONE tuple psum combines the weighted
         partial gradient sums with the [2] (weighted, surviving) counts —
         the per-round collective count stays at one."""
-        if cf is not None:
-            grads = grads * cf.astype(jnp.float32)[:, None, None]
-        if poison is not None:
-            grads = grads + poison.astype(jnp.float32)
-        fin = jnp.isfinite(grads).all(axis=(1, 2)).astype(jnp.float32)
-        cwe = cw * fin                       # exact: fin is 0.0/1.0
-        gsum = ops.packed_weighted_grad_sum(grads, cwe)
-        cnt = jnp.stack([cw.sum(), cwe.sum()])
-        gsum, cnt = jax.lax.psum((gsum, cnt), "data")
-        return losses, gsum, cnt
+        with jax.named_scope("round.aggregate"):
+            if cf is not None:
+                grads = grads * cf.astype(jnp.float32)[:, None, None]
+            if poison is not None:
+                grads = grads + poison.astype(jnp.float32)
+            fin = jnp.isfinite(grads).all(axis=(1, 2)).astype(jnp.float32)
+            cwe = cw * fin                       # exact: fin is 0.0/1.0
+            gsum = ops.packed_weighted_grad_sum(grads, cwe)
+            cnt = jnp.stack([cw.sum(), cwe.sum()])
+            gsum, cnt = jax.lax.psum((gsum, cnt), "data")
+            return losses, gsum, cnt
 
     @staticmethod
     def _robust_partial(losses, grads, cw, cf, poison=None):
@@ -1057,28 +1081,31 @@ class RoundEngine:
         invariant, so the sharded robust trajectory is bitwise identical
         to the unsharded one (stronger than the mean path, whose psum
         reassociates the sum — DESIGN.md §11)."""
-        if cf is not None:
-            grads = grads * cf.astype(jnp.float32)[:, None, None]
-        if poison is not None:
-            grads = grads + poison.astype(jnp.float32)
-        fin = jnp.isfinite(grads).all(axis=(1, 2)).astype(jnp.float32)
-        cwe = cw * fin                       # exact: fin is 0.0/1.0
-        ga, cwea = jax.lax.all_gather((grads, cwe), "data", axis=0,
-                                      tiled=True)
-        return losses, ga, cwea
+        with jax.named_scope("round.aggregate"):
+            if cf is not None:
+                grads = grads * cf.astype(jnp.float32)[:, None, None]
+            if poison is not None:
+                grads = grads + poison.astype(jnp.float32)
+            fin = jnp.isfinite(grads).all(axis=(1, 2)).astype(jnp.float32)
+            cwe = cw * fin                       # exact: fin is 0.0/1.0
+            ga, cwea = jax.lax.all_gather((grads, cwe), "data", axis=0,
+                                          tiled=True)
+            return losses, ga, cwea
 
     def _robust_tail(self, w, v, grads, cw_eff, noise):
         """Replicated robust tail: reduce the gathered full stack with the
         engine's aggregator and apply the same FMA-fenced inv=1.0 update
         as the single-device robust branch (bitwise-identical inputs ->
         bitwise-identical round)."""
-        ghat, ast = self.aggregator.reduce(grads, cw_eff)
-        n_ok = cw_eff.sum()
-        w2, g, step = ops.packed_apply_mean_update(
-            w, ghat, jnp.float32(1.0), self.eta, noise=noise)
-        alive = n_ok > 0.0
-        w2 = jnp.where(alive, w2, w)
-        g = jnp.where(alive, g, v)
+        with jax.named_scope("round.aggregate"):
+            ghat, ast = self.aggregator.reduce(grads, cw_eff)
+            n_ok = cw_eff.sum()
+        with jax.named_scope("round.update"):
+            w2, g, step = ops.packed_apply_mean_update(
+                w, ghat, jnp.float32(1.0), self.eta, noise=noise)
+            alive = n_ok > 0.0
+            w2 = jnp.where(alive, w2, w)
+            g = jnp.where(alive, g, v)
         return w2, g, step, n_ok.astype(jnp.int32), ast
 
     def _guarded_tail(self, w, v, gsum, cnt, inv, noise):
@@ -1087,16 +1114,17 @@ class RoundEngine:
         value-identically when every weighted client survived — the same
         contract as ops.packed_client_quarantine), apply the update, and
         carry (w, v) unchanged when no client survived."""
-        n_w, n_ok = cnt[0], cnt[1]
-        inv_eff = jnp.where(
-            n_ok == n_w, jnp.asarray(inv, jnp.float32),
-            jnp.where(n_ok > 0.0, 1.0 / jnp.maximum(n_ok, 1.0), 0.0))
-        w2, g, step = ops.packed_apply_mean_update(w, gsum, inv_eff,
-                                                   self.eta, noise=noise)
-        alive = n_ok > 0.0
-        w2 = jnp.where(alive, w2, w)
-        g = jnp.where(alive, g, v)
-        return w2, g, step, n_ok.astype(jnp.int32)
+        with jax.named_scope("round.update"):
+            n_w, n_ok = cnt[0], cnt[1]
+            inv_eff = jnp.where(
+                n_ok == n_w, jnp.asarray(inv, jnp.float32),
+                jnp.where(n_ok > 0.0, 1.0 / jnp.maximum(n_ok, 1.0), 0.0))
+            w2, g, step = ops.packed_apply_mean_update(w, gsum, inv_eff,
+                                                       self.eta, noise=noise)
+            alive = n_ok > 0.0
+            w2 = jnp.where(alive, w2, w)
+            g = jnp.where(alive, g, v)
+            return w2, g, step, n_ok.astype(jnp.int32)
 
     def _round_shared_sharded(self, w, v, xs, ys, sw, cw, inv, k, noise=None,
                               cf=None, poison=None):
@@ -1161,8 +1189,7 @@ class RoundEngine:
         def body(w_, v_, pr, thr_, xs_, ys_, sw_, cw_, *extra):
             # per-shard masks from the local thresholds: the batched
             # kernel reads the replicated (w, v) once, local masks only
-            _, masks = ops.packed_importance_masks(w_, v_, pr, thr_,
-                                                   impl=self.kernel_impl)
+            masks = self._client_masks(w_, v_, pr, thr_)
             losses, grads = self._client_grads_multi(w_, masks, xs_, ys_,
                                                      sw_)
             return partial(losses, grads, cw_,

@@ -74,6 +74,7 @@ def importance_mask_2d(w, v, threshold, *, block_rows: int = 256,
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     return pl.pallas_call(
         _importance_mask_kernel,
+        name="importance_mask",
         grid=grid,
         in_specs=[spec, spec, _SMEM],
         out_specs=[spec, spec],
@@ -111,6 +112,7 @@ def importance_mask_batched(w, v, prunable, thresholds, *,
     mspec = pl.BlockSpec((n_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _importance_mask_batched_kernel,
+        name="importance_mask_batched",
         grid=(r // br,),
         in_specs=[spec, spec, spec, _SMEM],
         out_specs=[spec, mspec],
@@ -151,6 +153,7 @@ def fedsgd_aggregate(w, grads, eta, *, block_rows: int = 256,
     gspec = pl.BlockSpec((n_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _fedsgd_aggregate_kernel,
+        name="fedsgd_aggregate",
         grid=(r // br,),
         in_specs=[spec, gspec, _SMEM],
         out_specs=[spec, spec, spec],
@@ -204,6 +207,7 @@ def fedsgd_aggregate_weighted(w, grads, cweights, inv, eta, *,
     gspec = pl.BlockSpec((n_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _fedsgd_aggregate_weighted_kernel,
+        name="fedsgd_aggregate_weighted",
         grid=(r // br,),
         in_specs=[spec, gspec, _SMEM, _SMEM],
         out_specs=[spec, spec, spec],
@@ -265,6 +269,7 @@ def client_rank_sort(grads, cweights, *, block_rows: int = 256,
     gspec = pl.BlockSpec((c_clients, br, c), lambda i: (0, i, 0))
     return pl.pallas_call(
         _client_rank_sort_kernel,
+        name="client_rank_sort",
         grid=(r // br,),
         in_specs=[gspec, _SMEM],
         out_specs=gspec,
@@ -327,6 +332,7 @@ def exponent_histogram(q, prunable, *, block_rows: int = 256,
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     hist = pl.pallas_call(
         _exponent_histogram_kernel,
+        name="exponent_histogram",
         grid=(r // br,),
         in_specs=[spec, spec],
         out_specs=pl.BlockSpec((256, c), lambda i: (0, 0)),
@@ -354,6 +360,7 @@ def masked_update_2d(w, g, mask, eta, *, block_rows: int = 256,
     spec = pl.BlockSpec((br, c), lambda i: (i, 0))
     return pl.pallas_call(
         _masked_update_kernel,
+        name="masked_update",
         grid=(r // br,),
         in_specs=[spec, spec, spec, _SMEM],
         out_specs=spec,

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models.layers import dense_init
 
 
@@ -204,18 +205,28 @@ def make_eval_fn(apply_fn, x_test, y_test, batch: int = 500):
 
     @jax.jit
     def _batch_eval(params, xb, yb):
-        logits = apply_fn(params, xb)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
-        acc = (logits.argmax(-1) == yb).mean()
-        return (lse - gold).mean(), acc
+        with jax.named_scope("eval"):
+            logits = apply_fn(params, xb)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            acc = (logits.argmax(-1) == yb).mean()
+            return (lse - gold).mean(), acc
 
     def eval_fn(params):
+        """(mean test loss, mean test accuracy) over the batches, each
+        batch's pair read to the host: one `eval` span, a `d2h` count per
+        read, and per batch an `eval.wait` around the first read, which
+        waits for the device (a wait of its own before the reads would
+        cost one more host-device round trip per batch)."""
         losses, accs = [], []
-        for i in range(0, len(y_test), batch):
-            l, a = _batch_eval(params, x_test[i:i + batch], y_test[i:i + batch])
-            losses.append(float(l))
-            accs.append(float(a))
+        with obs.span("eval") as sp:
+            for i in range(0, len(y_test), batch):
+                l, a = _batch_eval(params, x_test[i:i + batch],
+                                   y_test[i:i + batch])
+                with obs.span("eval.wait"):
+                    losses.append(float(l))
+                accs.append(float(a))
+                sp.count("d2h", 2)
         return float(np.mean(losses)), float(np.mean(accs))
 
     return eval_fn
