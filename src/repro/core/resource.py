@@ -11,8 +11,9 @@ Two solvers:
 * `solve_round_resources` (production): exact per-client decomposition. For a
   single round with per-round delay budget t, the clients decouple; each
   client's energy is a convex function of its (computation-time, upload-time)
-  split, minimized by golden-section search. An outer bisection allocates the
-  global delay budget across rounds.
+  split, minimized by golden-section search. `solve_schedule_resources`
+  gives every round the uniform budget t0/(S+1) and solves each distinct
+  (client, lambda) once per call.
 * `sca_round_resources` (paper-faithful): the eq. (28) SCA loop — iterate
   first-order Taylor linearization of the upload-energy term at p^(k) and
   solve the convexified subproblem with SLSQP until the objective decrease is
@@ -26,6 +27,7 @@ import dataclasses
 import numpy as np
 from scipy import optimize as sopt
 
+from repro import obs
 from repro.wireless.comm import (
     SystemParams, downlink_rate, uplink_rate,
     computation_delay, communication_delay,
@@ -198,37 +200,51 @@ def solve_schedule_resources(
 ) -> tuple[np.ndarray, np.ndarray, dict]:
     """(P2) across all rounds: returns p[S+1,N], f[S+1,N], info.
 
-    Channels are round-constant (paper Sec. V), so the optimal budget split is
-    uniform across rounds that share (a, lambda); we allocate each round the
-    budget t0/(S+1) scaled by a bisection factor that converts leftover delay
-    slack into energy savings until either budget binds.
+    Channels are round-constant (paper Sec. V), so every round gets the
+    uniform delay budget t0/(S+1). Under that budget a selected client's
+    min-energy allocation depends only on (n, lambda_n): each distinct pair
+    (lambda compared bit for bit) is solved once per call and shared by the
+    rounds that repeat it. Each round is assembled as `solve_round_resources`
+    does (broadcast energy, then the clients' energies in client order; the
+    straggler's delay), so the result is bitwise that of solving every round
+    on its own. Counts `p2.pairs` (selected (round, client) entries) and
+    `p2.solved` (allocations computed) on the innermost open span.
     """
     a = np.atleast_2d(a)
     lam = np.atleast_2d(lam)
-    n_rounds = a.shape[0]
-    base = t0 / max(n_rounds, 1)
-
-    def run(scale: float):
-        ps, fs, e_tot, t_tot, feas = [], [], 0.0, 0.0, True
-        for s in range(n_rounds):
-            ra = solve_round_resources(a[s], lam[s], base * scale, h_up, h_down, sp)
-            ps.append(ra.power)
-            fs.append(ra.freq)
-            e_tot += ra.energy
-            t_tot += ra.delay
-            feas &= ra.feasible
-        return np.array(ps), np.array(fs), e_tot, t_tot, feas
-
-    # More time => less energy. Find the largest uniform scale with T <= t0.
-    lo, hi = 1e-3, 1.0
-    best = run(1.0)
-    if best[3] > t0:  # even full budget infeasible in delay
-        return best[0], best[1], {"energy": best[2], "delay": best[3],
-                                  "feasible": False}
-    # expand time usage to reduce energy only if energy budget is violated
-    p, f, e_tot, t_tot, feas = best
-    info = {"energy": e_tot, "delay": t_tot, "feasible": feas and e_tot <= e0}
-    return p, f, info
+    n_rounds, n_cl = a.shape
+    t_budget = t0 / max(n_rounds, 1)
+    lam_bits = np.asarray(lam, dtype=np.float64).view(np.uint64)
+    e_bc = broadcast_energy(h_down, sp)
+    solved: dict[tuple[int, int], ClientAllocation] = {}
+    p, f = np.zeros((n_rounds, n_cl)), np.zeros((n_rounds, n_cl))
+    e_tot, t_tot, feas, n_pairs = 0.0, 0.0, True, 0
+    for s in range(n_rounds):
+        energy = e_bc if a[s].sum() else 0.0
+        delay = 0.0
+        feas_s = True
+        for n in range(n_cl):
+            if not a[s, n]:
+                continue
+            n_pairs += 1
+            key = (n, int(lam_bits[s, n]))
+            al = solved.get(key)
+            if al is None:
+                al = solved[key] = allocate_client(
+                    n, float(lam[s, n]), t_budget, h_up, h_down, sp)
+            p[s, n], f[s, n] = al.power, al.freq
+            energy += al.energy
+            delay = max(delay, al.delay)
+            feas_s &= al.feasible
+        e_tot += energy
+        t_tot += delay
+        feas &= feas_s
+    obs.count("p2.pairs", n_pairs)
+    obs.count("p2.solved", len(solved))
+    if t_tot > t0:  # the uniform budget misses the delay budget
+        return p, f, {"energy": e_tot, "delay": t_tot, "feasible": False}
+    return p, f, {"energy": e_tot, "delay": t_tot,
+                  "feasible": feas and e_tot <= e0}
 
 
 # --------------------------------------------------------------------------

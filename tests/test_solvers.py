@@ -1,8 +1,14 @@
 """(P2)-(P5) solvers + Algorithm 1 (AO)."""
+import time
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
+from repro.api.registry import SCHEMES
+from repro.api.spec import SchemeSpec
+from repro.core import optimizer_ao
 from repro.core.convergence import BoundConstants, theta
 from repro.core.optimizer_ao import AOConfig, solve_p1
 from repro.core.ratio import solve_pruning_ratios
@@ -66,6 +72,121 @@ def test_analytic_matches_sca(env):
     sca = sca_round_resources(a, lam, 1e9, t_round, ch.uplink, ch.downlink, sp)
     assert ana.feasible
     assert ana.energy <= sca.energy * 1.10  # decomposition is exact per client
+
+
+def per_round_schedule_resources(a, lam, e0, t0, h_up, h_down, sp):
+    """The schedule solve as one `solve_round_resources` per round at the
+    uniform budget t0/(S+1): every selected (round, client) solved anew."""
+    a, lam = np.atleast_2d(a), np.atleast_2d(lam)
+    t_round = t0 / max(a.shape[0], 1)
+    rounds = [solve_round_resources(a[s], lam[s], t_round, h_up, h_down, sp)
+              for s in range(a.shape[0])]
+    e_tot, t_tot, feas = 0.0, 0.0, True
+    for ra in rounds:
+        e_tot += ra.energy
+        t_tot += ra.delay
+        feas &= ra.feasible
+    feas = False if t_tot > t0 else feas and e_tot <= e0
+    return (np.array([ra.power for ra in rounds]),
+            np.array([ra.freq for ra in rounds]),
+            {"energy": e_tot, "delay": t_tot, "feasible": feas})
+
+
+def _t0(ch, sp, rounds, lam=0.0, slack=3.0):
+    return rounds * slack * max(
+        min_client_delay(i, lam, ch.uplink, ch.downlink, sp) for i in range(N))
+
+
+def _case(name, rounds):
+    """(a, lam, t0 scale, distinct (client, lambda) among the selected)."""
+    rng = np.random.default_rng(7)
+    a = np.ones((rounds, N))
+    lam = np.full((rounds, N), 0.2)
+    if name == "all_rounds_equal":
+        return a, lam, 1.0, N
+    if name == "one_client_per_round":
+        a = np.zeros((rounds, N))
+        a[np.arange(rounds), np.arange(rounds) % N] = 1.0
+        lam = rng.choice([0.0, 0.3, 0.5], size=(rounds, N))
+        return a, lam, 1.0, len({(s % N, lam[s, s % N])
+                                 for s in range(rounds)})
+    if name == "rows_with_a_zero":
+        a = (rng.uniform(size=(rounds, N)) < 0.5).astype(float)
+        a[0, :3] = 1.0
+        a[1] = 0.0
+        a[-1] = 0.0
+        lam = rng.choice([0.1, 0.4], size=(rounds, N))
+        sel = np.argwhere(a > 0)
+        return a, lam, 1.0, len({(n, lam[s, n]) for s, n in sel})
+    if name == "lambda_one_ulp_apart":
+        lam[1, 0] = np.nextafter(lam[0, 0], 1.0)
+        return a, lam, 1.0, N + 1
+    if name == "infeasible_budget":
+        return a, lam, 0.05, N
+    raise ValueError(name)
+
+
+CASES = ["all_rounds_equal", "one_client_per_round", "rows_with_a_zero",
+         "lambda_one_ulp_apart", "infeasible_budget"]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_schedule_resources_match_a_solve_per_round_bitwise(env, case):
+    """Solving each distinct (client, lambda) once gives the bits of a
+    solve per (round, client), and counts what it solved."""
+    sp, ch, c, _ = env
+    rounds = c.rounds_S + 1
+    a, lam, scale, distinct = _case(case, rounds)
+    t0 = scale * _t0(ch, sp, rounds)
+    args = (a, lam, 40.0, t0, ch.uplink, ch.downlink, sp)
+    t_start = time.perf_counter()
+    with obs.span("p2.test"):
+        p, f, info = solve_schedule_resources(*args)
+    (rec,) = [r for r in obs.between(t_start, time.perf_counter())
+              if r.name == "p2.test"]
+    p_ref, f_ref, info_ref = per_round_schedule_resources(*args)
+    for got, want in ((p, p_ref), (f, f_ref)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+    assert info == info_ref
+    assert [type(v) for v in info.values()] == [
+        type(v) for v in info_ref.values()]
+    assert info["feasible"] == (case != "infeasible_budget")
+    assert rec.counts == {"p2.pairs": int((a != 0).sum()),
+                          "p2.solved": distinct}
+
+
+@pytest.mark.parametrize("e0", [50.0, 0.3])
+def test_solve_p1_schedule_matches_a_solve_per_round(env, monkeypatch, e0):
+    """`proposed` through Algorithm 1: the same Schedule, bit for bit, as
+    with every (round, client) allocation solved anew."""
+    sp, ch, c, phi = env
+    cfg = SCHEMES.get("proposed")(SchemeSpec())
+    args = (phi, e0, _t0(ch, sp, c.rounds_S + 1), ch.uplink, ch.downlink,
+            sp, c, cfg)
+    got = solve_p1(*args)
+    monkeypatch.setattr(optimizer_ao, "solve_schedule_resources",
+                        per_round_schedule_resources)
+    want = solve_p1(*args)
+    for field in ("a", "lam", "power", "freq"):
+        x, y = getattr(got, field), getattr(want, field)
+        assert x.dtype == y.dtype and x.tobytes() == y.tobytes(), field
+    for field in ("theta", "energy", "delay", "feasible", "history"):
+        assert getattr(got, field) == getattr(want, field), field
+
+
+def test_ao_resources_span_counts_pairs_and_solves(env):
+    """Fixed selection and lambda: one P2 call on every (round, client),
+    one distinct lambda per client."""
+    sp, ch, c, phi = env
+    rounds = c.rounds_S + 1
+    t_start = time.perf_counter()
+    solve_p1(phi, 50.0, _t0(ch, sp, rounds), ch.uplink, ch.downlink, sp, c,
+             AOConfig(outer_iters=1, fix_lambda=0.2, fix_selection=True))
+    recs = [r for r in obs.between(t_start, time.perf_counter())
+            if r.name == "ao.resources"]
+    assert [r.counts for r in recs] == [{"p2.pairs": rounds * N,
+                                         "p2.solved": N}]
 
 
 # ---------------- pruning-ratio LP (P3) ----------------
