@@ -12,9 +12,14 @@ Dirichlet partition over clients reads only the labels, so with its seed
 fixed in the traffic file every seed gives every client the same number of
 samples and the same label mix, and the schedule solved from them is the
 same; the seed changes the images, the weights and the batch draws.
+
+`Images` is how such data is served to the system: its dataset registry
+reads the arrays, `num_classes` and `image_shape`.
 """
 from __future__ import annotations
 
+import copy
+import dataclasses
 import functools
 
 import jax
@@ -61,3 +66,35 @@ def make_images(cfg: dict, seed: int):
                 n_test=int(d["test"]), shape=tuple(d["image"]),
                 classes=int(d["classes"]))
     return tuple(np.asarray(a) for a in out)
+
+
+@dataclasses.dataclass
+class Images:
+    """The benchmark's images as the system's dataset registry serves
+    them."""
+    x_train: np.ndarray
+    y_train: np.ndarray
+    x_test: np.ndarray
+    y_test: np.ndarray
+    num_classes: int
+    name: str
+
+    @property
+    def image_shape(self):
+        return self.x_train.shape[1:]
+
+
+def images(cfg: dict, data) -> Images:
+    """`data` (from `make_images`) served under the configuration's name."""
+    return Images(*data, int(cfg["data"]["classes"]), cfg["name"])
+
+
+def test_size(files: dict) -> dict:
+    """A copy of an image cell's files at the CPU tests' size: 800
+    training and 100 test images, 4 clients, 6 rounds, eval every 3,
+    blocks of at most 2 rounds."""
+    files = copy.deepcopy(files)
+    files["config"]["data"].update(train=800, test=100)
+    files["traffic"].update(clients=4, rounds=6, eval_every=3,
+                            rounds_per_dispatch=2)
+    return files

@@ -4,8 +4,11 @@ Everything that belongs to one cell is data: `BENCHMARK.json` names the
 cell's configuration and traffic, `configs/<config>.json` holds the model
 and dataset sizes, `traffic/<traffic>.json` the federation, scheme and
 window unit, `workloads/<cell>.json` the limits `correct` is held to, and
-`metrics/<metric>.py` one reader per per-layer metric. Adding a cell, a
-configuration or a metric adds files; nothing here names one.
+`metrics/<metric>.py` one reader per per-layer metric. What belongs to one
+model (its data, the dataset the system is served, its weights, its plain
+forward pass and FLOP count) is `models/<model>.py`, found by the
+configuration's `model.name`. Adding a cell, a configuration, a model or
+a metric adds files; nothing here names one.
 
 A window unit is one whole federated run of the traffic's schedule through
 the system's normal path (`Experiment` -> `Run.run` -> `FederatedTrainer`
@@ -25,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import hashlib
 import importlib.util
 import json
 import math
@@ -136,10 +140,11 @@ def make_spec(cfg: dict, traffic: dict, seed: int):
     from repro.api import (DataSpec, ExperimentSpec, ModelSpec, RunSpec,
                            SchemeSpec, WirelessSpec)
     d, t = cfg["data"], traffic
+    noise = {"noise": d["noise"]} if "noise" in d else {}
     return ExperimentSpec(
         data=DataSpec(dataset="bench-" + cfg["name"], n_clients=t["clients"],
                       sigma=t["sigma"], n_train=d["train"], n_test=d["test"],
-                      noise=d["noise"], seed=t["partition_seed"]),
+                      seed=t["partition_seed"], **noise),
         model=ModelSpec(name=cfg["program_model"]["name"],
                         kwargs=dict(cfg["program_model"]["kwargs"])),
         wireless=WirelessSpec(e0=t["e0"], t0=t["t0"], seed=t["channel_seed"],
@@ -155,30 +160,14 @@ def make_spec(cfg: dict, traffic: dict, seed: int):
                     device_mem_budget=t["device_mem_budget"]))
 
 
-def register_dataset(cfg: dict, images) -> None:
-    """Serve the benchmark's images to the system under the name the spec
-    gives, through its dataset registry."""
+def register_dataset(cfg: dict, data) -> None:
+    """Serve the benchmark's data to the system under the name the spec
+    gives, through its dataset registry, as the model's file says."""
+    from bench import models
     from repro.api.registry import DATASETS
-    x_tr, y_tr, x_te, y_te = images
-
-    @dataclasses.dataclass
-    class Images:
-        x_train: np.ndarray
-        y_train: np.ndarray
-        x_test: np.ndarray
-        y_test: np.ndarray
-        num_classes: int
-        name: str
-
-        @property
-        def image_shape(self):
-            return self.x_train.shape[1:]
-
-    DATASETS.register(
-        "bench-" + cfg["name"],
-        lambda spec: Images(x_tr, y_tr, x_te, y_te,
-                            int(cfg["data"]["classes"]), cfg["name"]),
-        override=True)
+    model = models.of(cfg)
+    DATASETS.register("bench-" + cfg["name"],
+                      lambda spec: model.dataset(cfg, data), override=True)
 
 
 def unit_seed(seed: int, unit: int) -> int:
@@ -189,9 +178,10 @@ def unit_seed(seed: int, unit: int) -> int:
 @dataclasses.dataclass
 class Check:
     """What the first rounds of the program produced, and their feed."""
-    w0: object                 # the benchmark's weights (pytree)
+    w0: object                 # the benchmark's weights (host pytree)
     losses: list
-    blocks: list               # per block: (start, rounds, weights, grad)
+    blocks: list               # per block: (start, rounds, weights, grad),
+                               # host leaves in the program's dtype
     feed: list                 # per round: {"cids", "idxs", "lam"}
     programs: set              # block shapes (rounds, clients) dispatched
 
@@ -220,15 +210,15 @@ def run_check(run, cfg: dict, traffic: dict, seed: int) -> Check:
     from repro.api.callbacks import Callback
 
     class Snapshots(Callback):
-        """Host copies of the weights and the broadcast gradient after each
-        block: (start, rounds, weights, gradient)."""
+        """Host copies, in their own dtype, of the weights and the
+        broadcast gradient after each block: (start, rounds, weights,
+        gradient)."""
 
         def __init__(self):
             self.blocks: list[tuple[int, int, list, list]] = []
 
         def on_block_end(self, start, n_rounds, trainer):
-            host = lambda t: [np.asarray(a, np.float64)
-                              for a in jax.tree.leaves(t)]
+            host = lambda t: [np.asarray(a) for a in jax.tree.leaves(t)]
             self.blocks.append((start, n_rounds, host(trainer.params),
                                 host(trainer.global_grad)))
 
@@ -240,6 +230,7 @@ def run_check(run, cfg: dict, traffic: dict, seed: int) -> Check:
                            "system's parameter layout")
     check_seed = unit_seed(seed, (1 << 20) + 1)
     tr.reset(w0, check_seed)
+    w0 = jax.tree.map(np.asarray, w0)
     feed: list = []
     programs: set = set()
     engine = tr.engine
@@ -276,19 +267,25 @@ def run_check(run, cfg: dict, traffic: dict, seed: int) -> Check:
                  blocks=snaps.blocks, feed=feed, programs=programs)
 
 
+def row_key(row: np.ndarray) -> bytes:
+    """A digest of every byte of one row."""
+    return hashlib.blake2b(np.ascontiguousarray(row).tobytes(),
+                           digest_size=16).digest()
+
+
 def row_index(x: np.ndarray) -> dict:
-    """Rows of `x` by the bytes of their first 64 values."""
-    flat = x.reshape(len(x), -1)
-    return {flat[j, :64].tobytes(): j for j in range(len(flat))}
+    """Rows of `x` by a digest of the whole row: rows that share a long
+    prefix (a system prompt, a document asked about again) stay apart."""
+    return {row_key(x[j]): j for j in range(len(x))}
 
 
-def reference_rounds(check: Check, clients, images,
+def reference_rounds(check: Check, clients, data,
                      index: dict) -> tuple[list[dict], int]:
-    """Each round's batches from the benchmark's own images and labels.
+    """Each round's batches from the benchmark's own rows and labels.
     The program's client data says only which of the benchmark's rows a
-    client's sample is; a sample that is no row of the benchmark's images
+    client's sample is; a sample that is no row of the benchmark's data
     is counted (`foreign_rows`) and fed as the program has it."""
-    x_tr, y_tr = images[0], images[1]
+    x_tr, y_tr = data[0], data[1]
     rounds, foreign = [], 0
     for f in check.feed:
         xs, ys = [], []
@@ -296,7 +293,7 @@ def reference_rounds(check: Check, clients, images,
             rows = []
             for i in batch:
                 got = np.asarray(clients[c].x[i])
-                j = index.get(got.reshape(-1)[:64].tobytes())
+                j = index.get(row_key(got))
                 if j is None or not np.array_equal(x_tr[j], got):
                     foreign += 1
                     j = None
@@ -309,7 +306,7 @@ def reference_rounds(check: Check, clients, images,
     return rounds, foreign
 
 
-def readings(check: Check, clients, images, cfg: dict, traffic: dict,
+def readings(check: Check, clients, data, cfg: dict, traffic: dict,
              stand_ins: tuple = ()) -> dict:
     """The compared numbers of the program against the float32 reference,
     and of each stand-in put in the program's place: "control" (the
@@ -320,8 +317,8 @@ def readings(check: Check, clients, images, cfg: dict, traffic: dict,
     import jax
     import jax.numpy as jnp
     from bench import reference
-    rounds, foreign = reference_rounds(check, clients, images,
-                                       row_index(images[0]))
+    rounds, foreign = reference_rounds(check, clients, data,
+                                       row_index(data[0]))
     kw = {"reference": {}, "control": {"dtype": jnp.bfloat16},
           "half_batch": {"half_batch": True}}
 
@@ -336,7 +333,7 @@ def readings(check: Check, clients, images, cfg: dict, traffic: dict,
         ref = reference.follow(model("reference"), check.w0, rounds, run)
         return reference.compare(run, ref, w0)
 
-    w0 = [np.asarray(a, np.float64) for a in jax.tree.leaves(check.w0)]
+    w0 = jax.tree.leaves(check.w0)
     lengths = [b[1] for b in check.blocks]
     out = {"program": against_reference(
         {"losses": check.losses, "blocks": check.blocks})}
@@ -364,18 +361,19 @@ def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:
 
 def set_up(cfg: dict, traffic: dict, seed: int):
     """The cell's data, environment, schedule and trainer, through the
-    system's normal path. Returns (spec, env, run, images); the images are
-    the benchmark's own, read-only, and the system is given them."""
-    from bench import data
+    system's normal path. Returns (spec, env, run, data); the data are the
+    benchmark's own, made by the model's file, read-only, and the system
+    is given them."""
+    from bench import models
     from repro.api import Experiment
     from repro.api.experiment import build_environment
-    images = data.make_images(cfg, seed)
-    for a in images:
+    data = models.of(cfg).make_data(cfg, seed)
+    for a in data:
         a.setflags(write=False)
-    register_dataset(cfg, images)
+    register_dataset(cfg, data)
     spec = make_spec(cfg, traffic, unit_seed(seed, 0))
     env = build_environment(spec)
-    return spec, env, Experiment(spec).build(env=env), images
+    return spec, env, Experiment(spec).build(env=env), data
 
 
 # ---------------------------------------------------------------------------
@@ -394,7 +392,7 @@ def run_cell(bench: dict, cell: dict, files: dict, *, seed: int,
 
     # -- set-up -------------------------------------------------------------
     with spans.span("setup.build"):
-        spec, env, run, images = set_up(cfg, traffic, seed)
+        spec, env, run, data = set_up(cfg, traffic, seed)
     trainer = run.trainer
     with spans.span("setup.check"):
         check = run_check(run, cfg, traffic, seed)
@@ -463,9 +461,12 @@ def run_cell(bench: dict, cell: dict, files: dict, *, seed: int,
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
 
-    # -- the check, after the window ----------------------------------------
-    numbers = readings(check, trainer.clients, images, cfg,
-                       traffic)["program"]
+    # -- the check, after the window, the program's state freed -------------
+    clients = trainer.clients
+    selected_per_round = [int(a.sum()) for a in run.schedule.a]
+    del one_unit, block_step, run, trainer, env, spec
+    gc.collect()
+    numbers = readings(check, clients, data, cfg, traffic)["program"]
     numbers["unchecked_programs"] = len(window_programs - check.programs)
     correct, numbers = judge(numbers, limits)
 
@@ -490,7 +491,7 @@ def run_cell(bench: dict, cell: dict, files: dict, *, seed: int,
             cfg=cfg, traffic=traffic, chips=chips,
             peaks=load_json(BENCH / "peaks.json")["devices"].get(
                 devices[0].device_kind),
-            selected_per_round=[int(a.sum()) for a in run.schedule.a])
+            selected_per_round=selected_per_round)
         metrics = {}
         for m in cell_metrics(bench, cell, "per_layer"):
             v = load_reader(m["name"])(ctx)

@@ -40,116 +40,27 @@ F32 = jnp.float32
 
 
 # ---------------------------------------------------------------------------
-# models (NHWC images, HWIO kernels), in the layout the configuration files
-# describe
+# the models: one file each, bench/models/<model>.py, in the layout the
+# configuration files describe; layers they share
 # ---------------------------------------------------------------------------
 
-def _conv(x, w, stride, precision):
+def conv(x, w, stride, precision):
+    """A SAME convolution of NHWC images with an HWIO kernel."""
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
 
 
-def _norm_relu(x, scale, bias):
-    """Per-sample, per-channel normalization over the spatial axes, then
-    scale, shift and ReLU."""
-    mu = x.mean(axis=(1, 2), keepdims=True)
-    var = jnp.square(x - mu).mean(axis=(1, 2), keepdims=True)
-    return jax.nn.relu((x - mu) / jnp.sqrt(var + 1e-5) * scale + bias)
-
-
-def resnet_forward(p, x, precision):
-    """CIFAR ResNet (He et al. 2016, Sec. 4.2): a 3x3 stem, three stages of
-    n basic blocks at widths w, 2w, 4w (the first block of stages 2 and 3
-    strides 2 and projects its shortcut with a strided 1x1 convolution, as
-    the configuration's `layout` and `assumed` say, where Sec. 4.2 pads the
-    identity with zeros), global average pooling and a linear head."""
-    x = _conv(x, p["stem"], 1, precision)
-    for blk in p["blocks"]:
-        stride = 2 if blk["conv1"].shape[2] != blk["conv1"].shape[3] else 1
-        h = _norm_relu(_conv(x, blk["conv1"], stride, precision),
-                       blk["scale1"], blk["bias1"])
-        h = _norm_relu(_conv(h, blk["conv2"], 1, precision),
-                       blk["scale2"], blk["bias2"])
-        sc = _conv(x, blk["proj"], stride, precision) if "proj" in blk else x
-        x = jax.nn.relu(h + sc)
-    x = x.mean(axis=(1, 2))
-    return jnp.matmul(x, p["head"], precision=precision) + p["head_b"]
-
-
-def _pool2(x):
-    b, h, w, c = x.shape
-    return x.reshape(b, h // 2, 2, w // 2, 2, c).max(axis=(2, 4))
-
-
-def lenet_forward(p, x, precision):
-    """LeNet-5 (LeCun et al. 1998) with SAME-padded 5x5 convolutions:
-    conv 6, pool, conv 16, pool, dense 120, 84, classes; ReLU throughout."""
-    x = _pool2(jax.nn.relu(_conv(x, p["conv1"], 1, precision)))
-    x = _pool2(jax.nn.relu(_conv(x, p["conv2"], 1, precision)))
-    x = x.reshape(x.shape[0], -1)
-    mm = lambda a, b: jnp.matmul(a, b, precision=precision)
-    x = jax.nn.relu(mm(x, p["fc1"]) + p["b1"])
-    x = jax.nn.relu(mm(x, p["fc2"]) + p["b2"])
-    return mm(x, p["fc3"]) + p["b3"]
-
-
-FORWARD = {"resnet": resnet_forward, "lenet": lenet_forward}
-
-
-def _normal(key, shape, fan_in):
+def normal(key, shape, fan_in):
+    """Normal weights with variance 1/fan_in."""
     return jax.random.normal(key, shape, F32) / math.sqrt(fan_in)
 
 
 def init_params(cfg: dict, key):
-    """Weights for `cfg["model"]` from `key`: normal with variance 1/fan_in,
-    every bias 0, norm scales 1 except the second norm of each residual
-    block, which starts at the configuration's `init.residual_scale`. Jit
-    it: one device call makes them."""
-    m = cfg["model"]
-    h, w, c_in = cfg["data"]["image"]
-    nc = cfg["data"]["classes"]
-    ks = iter(jax.random.split(key, 4096))
-    if m["name"] == "lenet":
-        c1, c2, f1, f2 = m["widths"]
-        k = m["kernel"]
-        flat = (h // 4) * (w // 4) * c2
-        return {
-            "conv1": _normal(next(ks), (k, k, c_in, c1), k * k * c_in),
-            "conv2": _normal(next(ks), (k, k, c1, c2), k * k * c1),
-            "fc1": _normal(next(ks), (flat, f1), flat),
-            "b1": jnp.zeros((f1,), F32),
-            "fc2": _normal(next(ks), (f1, f2), f1),
-            "b2": jnp.zeros((f2,), F32),
-            "fc3": _normal(next(ks), (f2, nc), f2),
-            "b3": jnp.zeros((nc,), F32),
-        }
-    if m["name"] == "resnet":
-        n = (m["depth"] - 2) // 6
-        width = m["width"]
-        rs = cfg.get("init", {}).get("residual_scale", 1.0)
-        p = {"stem": _normal(next(ks), (3, 3, c_in, width), 9 * c_in)}
-        blocks, c = [], width
-        for c_out in (width, 2 * width, 4 * width):
-            for _ in range(n):
-                blk = {
-                    "conv1": _normal(next(ks), (3, 3, c, c_out), 9 * c),
-                    "conv2": _normal(next(ks), (3, 3, c_out, c_out),
-                                     9 * c_out),
-                    "scale1": jnp.ones((c_out,), F32),
-                    "bias1": jnp.zeros((c_out,), F32),
-                    "scale2": jnp.full((c_out,), rs, F32),
-                    "bias2": jnp.zeros((c_out,), F32),
-                }
-                if c != c_out:
-                    blk["proj"] = _normal(next(ks), (1, 1, c, c_out), c)
-                blocks.append(blk)
-                c = c_out
-        p["blocks"] = blocks
-        p["head"] = _normal(next(ks), (c, nc), c)
-        p["head_b"] = jnp.zeros((nc,), F32)
-        return p
-    raise ValueError(f"no reference for model {m['name']!r}")
+    """Weights for `cfg["model"]` from `key`, by its model's file. Jit it:
+    one device call makes them."""
+    from bench import models
+    return models.of(cfg).init_params(cfg, key)
 
 
 def leaf_names(tree) -> list[str]:
@@ -180,13 +91,16 @@ class Reference:
         self.eta = float(eta)
         self.dtype = dtype
         self.half_batch = half_batch
-        fwd = FORWARD[cfg["model"]["name"]]
+        from bench import models
+        fwd = models.of(cfg).forward
         prec = jax.lax.Precision[cfg["matmul_precision"].upper()]
 
         def loss(p, x, y):
+            """Mean cross-entropy over every label: one per image, or one
+            per position of a sequence."""
             logits = fwd(p, x, prec)
             lse = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+            gold = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
             return (lse - gold).mean()
 
         self._grad = jax.jit(jax.value_and_grad(loss))
@@ -224,12 +138,13 @@ class Reference:
 
     def run(self, w0, rounds: list[dict], *, v0=None,
             blocks: list[int] | None = None) -> dict:
-        """Follow `rounds` (each {"x": [C,B,...], "y": [C,B], "lam": [C]},
-        the real clients only) from weights `w0` and broadcast gradient
-        `v0` (zero before round 0). `blocks` splits the rounds into runs
-        of the given lengths (default: one). Returns host copies: the
-        per-round mean loss and, after each block, (start, length, the
-        weights, the broadcast gradient)."""
+        """Follow `rounds` (each {"x": [C,B,...], "y": [C,B,...], "lam":
+        [C]}, the real clients only) from weights `w0` and broadcast
+        gradient `v0` (zero before round 0). `blocks` splits the rounds
+        into runs of the given lengths (default: one). Returns host
+        copies, in the reference's dtype: the per-round mean loss and,
+        after each block, (start, length, the weights, the broadcast
+        gradient)."""
         dt = self.dtype
         w = jax.tree.map(lambda a: jnp.asarray(a, dt), w0)
         if self._flags is None:
@@ -266,7 +181,23 @@ class Reference:
 
 
 def _host(tree) -> list[np.ndarray]:
-    return [np.asarray(a, np.float64) for a in jax.tree.leaves(tree)]
+    return [np.asarray(a) for a in jax.tree.leaves(tree)]
+
+
+# ---------------------------------------------------------------------------
+# the comparison, by norms of leaves taken in float64 one leaf at a time:
+# the check keeps states in their own dtype and of the reference only norms
+# ---------------------------------------------------------------------------
+
+def change_norms(w: list, start: list) -> list[float]:
+    """Per leaf, the norm of `w - start`, both widened to float64."""
+    return [float(np.linalg.norm(np.subtract(a, b, dtype=np.float64)))
+            for a, b in zip(w, start)]
+
+
+def grad_norms(v: list) -> list[float]:
+    """Per leaf, the norm of `v` widened to float64."""
+    return [float(np.linalg.norm(np.asarray(a, np.float64))) for a in v]
 
 
 def follow(ref: Reference, w0, rounds: list[dict], run: dict) -> dict:
@@ -274,39 +205,43 @@ def follow(ref: Reference, w0, rounds: list[dict], run: dict) -> dict:
     place), each block from the state `run` held at its start: the weights
     and broadcast gradient it returned for the block before, or `w0` and
     zero for the first. So each block program is judged on its own input,
-    and a difference does not carry over from one block to the next."""
+    and a difference does not carry over from one block to the next.
+
+    Of the reference only what `compare` reads is kept: the per-round
+    losses and, per block, (start, length, the norms of each leaf's change
+    over the block, the norms of the broadcast gradient's leaves). Each
+    block's state is dropped once they are taken."""
     treedef = jax.tree.structure(w0)
-    w, v = w0, None
-    losses, snaps = [], []
+    w, v, start_w = w0, None, jax.tree.leaves(w0)
+    losses, norms = [], []
     for start, n, w_end, v_end in run["blocks"]:
         r = ref.run(w, rounds[start:start + n], v0=v)
         losses += r["losses"]
-        snaps.append((start, n) + tuple(r["blocks"][0][2:]))
+        _, _, w_ref, v_ref = r["blocks"][0]
+        norms.append((start, n, change_norms(w_ref, start_w),
+                      grad_norms(v_ref)))
         w = jax.tree.unflatten(treedef, [np.asarray(a, np.float32)
                                          for a in w_end])
         v = jax.tree.unflatten(treedef, [np.asarray(a, np.float32)
                                          for a in v_end])
-    return {"losses": losses, "blocks": snaps}
+        start_w = w_end
+    return {"losses": losses, "blocks": norms}
 
 
-# ---------------------------------------------------------------------------
-# the comparison
-# ---------------------------------------------------------------------------
-
-def _leaf_norm_gaps(got: list, want: list, keep: list[bool]) -> list:
-    """|norm(got leaf) - norm(want leaf)| over the larger of the want
-    leaf's norm and the median want leaf's norm, for each kept leaf."""
-    ng = [float(np.linalg.norm(a)) for a in got]
-    nw = [float(np.linalg.norm(b)) for b in want]
-    med = float(np.median(nw))
+def _norm_gaps(got: list, want: list, keep: list[bool]) -> list:
+    """|got - want| over the larger of the want leaf's norm and the median
+    want leaf's norm, for each kept leaf, from per-leaf norms."""
+    med = float(np.median(want))
     return [abs(a - b) / max(b, med) if max(b, med) > 0 else 0.0
-            for a, b, k in zip(ng, nw, keep) if k]
+            for a, b, k in zip(got, want, keep) if k]
 
 
 def compare(run: dict, ref: dict, w0: list) -> dict:
     """The numbers `correct` holds to limits, `run` (the program, or a
-    stand-in in its place) against `ref`, the reference that `follow` led
-    over the same blocks from the same start states:
+    stand-in in its place, with its states after each block) against
+    `ref`, the norms that `follow` took of the reference led over the same
+    blocks from the same start states; `w0` are the leaves of the weights
+    both started from:
 
     loss_gap    largest relative gap of the mean loss of a block's first
                 round, which both sides compute from the same state;
@@ -326,17 +261,15 @@ def compare(run: dict, ref: dict, w0: list) -> dict:
     the gradient after it, change gap) of each block."""
     lp, lr = np.asarray(run["losses"]), np.asarray(ref["losses"])
     rel = np.abs(lp - lr) / np.abs(lr)
-    gn = [float(np.linalg.norm(g)) for g in ref["blocks"][0][3]]
+    gn = ref["blocks"][0][3]
     floor = 1e-3 * float(np.median(gn))
     keep = [g >= floor for g in gn]
     start_w = w0
     by_block = []
-    for (s, n, w_run, v_run), (_, _, w_ref, v_ref) in zip(run["blocks"],
+    for (s, n, w_run, v_run), (_, _, c_ref, g_ref) in zip(run["blocks"],
                                                           ref["blocks"]):
-        g = max(_leaf_norm_gaps(v_run, v_ref, [True] * len(w0)))
-        c = max(_leaf_norm_gaps([a - b for a, b in zip(w_run, start_w)],
-                                [a - b for a, b in zip(w_ref, start_w)],
-                                keep))
+        g = max(_norm_gaps(grad_norms(v_run), g_ref, [True] * len(w0)))
+        c = max(_norm_gaps(change_norms(w_run, start_w), c_ref, keep))
         by_block.append((s, n, float(rel[s]), g, c))
         start_w = w_run
     return {"loss_gap": max(b[2] for b in by_block),

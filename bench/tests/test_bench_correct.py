@@ -9,14 +9,13 @@ program the window uses comes out not correct; and the control, the
 reference in bfloat16 put in the program's place, fails the cell's limits.
 The device check is skipped; everything else is a whole run.
 """
-import copy
 import time
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import harness, models
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = harness.load_benchmark(ROOT)
@@ -24,17 +23,11 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 def tiny_files(cell_name: str) -> dict:
-    """The cell's files at a test size: the model's widths are kept, the
-    ResNet is cut to depth 8, the data and the federation shrink."""
+    """The cell's files at a test size, as the model's file cuts them: the
+    model's widths are kept, the data and the federation shrink."""
     cell = harness.find_cell(BENCH, cell_name)
-    files = copy.deepcopy(harness.cell_files(BENCH, cell, ROOT))
-    cfg, tr = files["config"], files["traffic"]
-    if cfg["model"]["name"] == "resnet":
-        cfg["model"]["depth"] = 8
-        cfg["program_model"]["kwargs"]["depth"] = 8
-    cfg["data"].update(train=800, test=100)
-    tr.update(clients=4, rounds=6, eval_every=3, rounds_per_dispatch=2)
-    return files
+    files = harness.cell_files(BENCH, cell, ROOT)
+    return models.of(files["config"]).test_size(files)
 
 
 def run(cell_name: str, files: dict) -> dict:
@@ -52,12 +45,12 @@ def broken(monkeypatch):
     from repro.core.round_engine import RoundEngine
     original = RoundEngine.block_step
 
-    def plant(kind):
+    def plant(kind, classes):
         if kind == "wrong_labels":
             client_data_class = experiment.ClientData
 
             def client_data(x, y):
-                return client_data_class(x, (y + 1) % 10)
+                return client_data_class(x, (y + 1) % classes)
             monkeypatch.setattr(experiment, "ClientData", client_data)
             return
 
@@ -78,9 +71,10 @@ def broken(monkeypatch):
 @pytest.mark.parametrize("fault", [None, "state_unchanged", "half_batch",
                                    "wrong_labels"])
 def test_a_planted_fault_makes_the_run_not_correct(cell, fault, broken):
+    files = tiny_files(cell)
     if fault:
-        broken(fault)
-    out = run(cell, tiny_files(cell))
+        broken(fault, files["config"]["data"]["classes"])
+    out = run(cell, files)
     assert out["correct"] is (fault is None), out["compared"]
     if fault is None:
         assert out["compared"]["unchecked_programs"]["value"] == 0
