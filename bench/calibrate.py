@@ -7,10 +7,11 @@ For each seed, in one process: the cell's set-up and the check's first
 rounds through the system, then the compared numbers of the program
 against the float32 reference and of each stand-in put in the program's
 place (the bfloat16 control; the reference with half of each batch left
-out), each block followed from the state the program or the stand-in
-held at its start. No window is run: a training cell's readings need none. One JSON
-line per seed, then the largest program reading and the smallest stand-in
-reading of each number.
+out; where the traffic asks for a trimmed mean, the reference with the
+mean in its place), each block followed from the state the program or
+the stand-in held at its start. No window is run: a training cell's
+readings need none. One JSON line per seed, then the largest program
+reading and the smallest stand-in reading of each number.
 
 A "state left unchanged" fault reads 1 on `change_gap` by its definition
 (no change against the reference's) and needs no run.
@@ -49,7 +50,7 @@ def main(argv=None) -> int:
         print(f"bench/calibrate.py: {why}", file=sys.stderr)
         return 2
     cfg, traffic = files["config"], files["traffic"]
-    stand_ins = ("control", "half_batch")
+    stand_ins = harness.stand_ins_of(traffic)
     rows = []
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()

@@ -21,6 +21,7 @@ from __future__ import annotations
 import copy
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -92,9 +93,13 @@ def images(cfg: dict, data) -> Images:
 def test_size(files: dict) -> dict:
     """A copy of an image cell's files at the CPU tests' size: 800
     training and 100 test images, 4 clients, 6 rounds, eval every 3,
-    blocks of at most 2 rounds."""
+    blocks of at most 2 rounds. Where the traffic trims a share beta of
+    the clients from each end, as many clients as make it trim one,
+    floor(beta * clients) = 1, and a bucket with padding clients."""
     files = copy.deepcopy(files)
     files["config"]["data"].update(train=800, test=100)
-    files["traffic"].update(clients=4, rounds=6, eval_every=3,
+    beta = files["traffic"]["aggregator_kwargs"].get("beta")
+    clients = max(4, math.ceil(1 / beta) + 1) if beta else 4
+    files["traffic"].update(clients=clients, rounds=6, eval_every=3,
                             rounds_per_dispatch=2)
     return files

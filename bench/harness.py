@@ -306,24 +306,39 @@ def reference_rounds(check: Check, clients, data,
     return rounds, foreign
 
 
+def stand_ins_of(traffic: dict) -> tuple:
+    """The stand-ins a cell's limits are calibrated against: the control,
+    the half-batch fault and, where the traffic asks for a trimmed mean,
+    the mean in its place."""
+    return ("control", "half_batch") + (
+        ("mean_for_trimmed",) if traffic["aggregator"] == "trimmed_mean"
+        else ())
+
+
 def readings(check: Check, clients, data, cfg: dict, traffic: dict,
              stand_ins: tuple = ()) -> dict:
-    """The compared numbers of the program against the float32 reference,
-    and of each stand-in put in the program's place: "control" (the
-    reference in bfloat16) and "half_batch" (the reference with half of
-    each batch left out, the mean taken over the rest). The reference
-    follows each block from the state the program, or the stand-in, held
-    at its start."""
+    """The compared numbers of the program against the float32 reference
+    with the traffic's aggregator, and of each stand-in put in the
+    program's place: "control" (the reference in bfloat16), "half_batch"
+    (the reference with half of each batch left out, the mean taken over
+    the rest) and "mean_for_trimmed" (the reference with the mean where
+    the traffic asks for the trimmed mean). The reference follows each
+    block from the state the program, or the stand-in, held at its
+    start."""
     import jax
     import jax.numpy as jnp
     from bench import reference
     rounds, foreign = reference_rounds(check, clients, data,
                                        row_index(data[0]))
-    kw = {"reference": {}, "control": {"dtype": jnp.bfloat16},
-          "half_batch": {"half_batch": True}}
+    agg = {"aggregator": traffic["aggregator"],
+           "aggregator_kwargs": dict(traffic["aggregator_kwargs"])}
+    kw = {"reference": agg, "control": {**agg, "dtype": jnp.bfloat16},
+          "half_batch": {**agg, "half_batch": True},
+          "mean_for_trimmed": {"aggregator": "mean"}}
 
     def model(name):
-        key = (json.dumps(cfg, sort_keys=True), traffic["eta"], name)
+        key = (json.dumps(cfg, sort_keys=True), traffic["eta"],
+               json.dumps(agg, sort_keys=True), name)
         if key not in _REFERENCES:        # one set of compiled rounds
             _REFERENCES[key] = reference.Reference(cfg, traffic["eta"],
                                                    **kw[name])

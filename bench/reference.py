@@ -15,7 +15,9 @@ schedule's selection and pruning ratios. Per round s and selected client n:
      (eq. 5);
   4. the server averages the masked gradients of the selected clients
      (eq. 6), steps w <- w - eta * mean (eq. 7), and broadcasts the mean as
-     the next round's v.
+     the next round's v. Where the traffic names a robust aggregator in
+     place of the mean, the server reduces the same masked gradients by
+     that rule (`AGGREGATORS`), steps by the result and broadcasts it.
 
 The threshold is compared on the device, like everything else here: a
 device that flushes subnormal floats to zero reads ``nextafter(0, inf)`` as
@@ -26,7 +28,8 @@ float32 arrays, matrix products and convolutions at the configuration's
 `matmul_precision` ("default": on a TPU, float32 operands rounded to
 bfloat16 in one pass, accumulated in float32). ``dtype=jnp.bfloat16``
 gives the control, the nearest precision below: the same arithmetic with
-every array in bfloat16.
+every array in bfloat16. Integer inputs (token ids) stay integers, in
+int32, in every precision.
 """
 from __future__ import annotations
 
@@ -79,16 +82,60 @@ def prunable_flags(cfg: dict, tree) -> list[bool]:
 # the rounds
 # ---------------------------------------------------------------------------
 
+def trimmed_mean(stack, beta: float):
+    """Per coordinate over the leading (client) axis of `stack`: sort, drop
+    the floor(beta * n) smallest and the floor(beta * n) largest of the n
+    values, and take the mean of the rest."""
+    n = stack.shape[0]
+    t = math.floor(beta * n)
+    return jnp.sort(stack, axis=0)[t:n - t].mean(axis=0)
+
+
+# the server's reductions of the selected clients' masked gradients that
+# the reference states, by the name a traffic file gives
+AGGREGATORS = ("mean", "trimmed_mean")
+
+
+def _beta(aggregator: str, kwargs: dict) -> float | None:
+    """The trimmed share of `aggregator`'s `kwargs`, or None for the mean.
+    An aggregator the reference does not state, or a setting it does not
+    know or lacks, is an error: it never falls back to the mean."""
+    if aggregator not in AGGREGATORS:
+        raise ValueError(f"the reference states no aggregator {aggregator!r}"
+                         f"; it states {list(AGGREGATORS)}")
+    want = {"beta"} if aggregator == "trimmed_mean" else set()
+    if set(kwargs) != want:
+        raise ValueError(f"the reference's {aggregator!r} takes the settings "
+                         f"{sorted(want)}, got {sorted(kwargs)}")
+    if not want:
+        return None
+    beta = float(kwargs["beta"])
+    if not 0.0 <= beta < 0.5:
+        raise ValueError(f"trimmed_mean beta must be in [0, 0.5), got {beta}")
+    return beta
+
+
+def as_input(a, dtype):
+    """An input array in the reference's `dtype` where it holds floats;
+    integers (token ids, labels) in int32, whatever the dtype."""
+    a = np.asarray(a)
+    return jnp.asarray(a, dtype if np.issubdtype(a.dtype, np.floating)
+                       else jnp.int32)
+
+
 class Reference:
     """The rounds of one configuration, in float32 at the configuration's
-    matmul precision or (the control) in bfloat16. `half_batch=True` is a
-    planted fault: each client's gradient from the first half of its batch
-    only."""
+    matmul precision or (the control) in bfloat16, with the server's
+    `aggregator` ("mean", or "trimmed_mean" with `aggregator_kwargs`
+    {"beta": share}). `half_batch=True` is a planted fault: each client's
+    gradient from the first half of its batch only."""
 
-    def __init__(self, cfg: dict, eta: float, *, dtype=F32,
+    def __init__(self, cfg: dict, eta: float, *, aggregator: str = "mean",
+                 aggregator_kwargs: dict | None = None, dtype=F32,
                  half_batch: bool = False):
         self.cfg = cfg
         self.eta = float(eta)
+        self.beta = _beta(aggregator, aggregator_kwargs or {})
         self.dtype = dtype
         self.half_batch = half_batch
         from bench import models
@@ -108,6 +155,7 @@ class Reference:
         self._client = jax.jit(self._client_impl)
         self._add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b))
         self._step = jax.jit(self._step_impl)
+        self._trimmed_step = jax.jit(self._trimmed_step_impl)
         self._flags = None
 
     def _thresholds_impl(self, w, v, ks):
@@ -136,6 +184,11 @@ class Reference:
         mean = jax.tree.map(lambda a: a / n, acc)
         return jax.tree.map(lambda a, b: a - self.eta * b, w, mean), mean
 
+    def _trimmed_step_impl(self, w, grads):
+        ghat = jax.tree.map(lambda *g: trimmed_mean(jnp.stack(g), self.beta),
+                            *grads)
+        return jax.tree.map(lambda a, b: a - self.eta * b, w, ghat), ghat
+
     def run(self, w0, rounds: list[dict], *, v0=None,
             blocks: list[int] | None = None) -> dict:
         """Follow `rounds` (each {"x": [C,B,...], "y": [C,B,...], "lam":
@@ -162,16 +215,22 @@ class Reference:
             lam = np.asarray(r["lam"], np.float64)
             ks = jnp.asarray(np.floor(lam * m_prunable).astype(np.int32))
             thr = self._thresholds(w, v, ks)
-            acc, ls = None, []
+            acc, grads, ls = None, [], []
             for c in range(len(lam)):
-                x = jnp.asarray(r["x"][c], dt)
+                x = as_input(r["x"][c], dt)
                 y = jnp.asarray(r["y"][c], jnp.int32)
                 if self.half_batch:
                     x, y = x[: len(y) // 2], y[: len(y) // 2]
                 loss, g = self._client(w, v, thr[c], x, y)
                 ls.append(loss)
-                acc = g if acc is None else self._add(acc, g)
-            w, v = self._step(w, acc, jnp.asarray(len(lam), dt))
+                if self.beta is not None:
+                    grads.append(g)
+                else:        # the running sum in client order, over n
+                    acc = g if acc is None else self._add(acc, g)
+            if self.beta is not None:
+                w, v = self._trimmed_step(w, grads)
+            else:
+                w, v = self._step(w, acc, jnp.asarray(len(lam), dt))
             losses.append(float(np.mean(np.asarray(
                 [np.float32(l) for l in ls], np.float64))))
             if s + 1 in ends:

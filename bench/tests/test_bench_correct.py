@@ -7,8 +7,11 @@ each batch left out, the mean taken over the rest; a client's labels
 altered where its data is built); a check too short to reach every block
 program the window uses comes out not correct; and the control, the
 reference in bfloat16 put in the program's place, fails the cell's limits.
-The device check is skipped; everything else is a whole run.
+LeNet-5 with a traffic that asks for a trimmed mean is correct, and not
+with the mean in its place, and every stand-in fails its limits. The
+device check is skipped; everything else is a whole run.
 """
+import json
 import time
 from pathlib import Path
 
@@ -20,6 +23,8 @@ from bench import harness, models
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = harness.load_benchmark(ROOT)
 CELLS = [w["name"] for w in BENCH["workloads"]]
+TRIMMED_TRAFFIC = Path(__file__).resolve().parent / "data" / "trimmed" / \
+    "traffic.json"
 
 
 def tiny_files(cell_name: str) -> dict:
@@ -27,6 +32,16 @@ def tiny_files(cell_name: str) -> dict:
     model's widths are kept, the data and the federation shrink."""
     cell = harness.find_cell(BENCH, cell_name)
     files = harness.cell_files(BENCH, cell, ROOT)
+    return models.of(files["config"]).test_size(files)
+
+
+def trimmed_files() -> dict:
+    """LeNet-5's cell with a traffic that asks for a trimmed mean (every
+    client every round, beta 0.1, no eval) in place of its own, at a test
+    size, held to LeNet-5's limits."""
+    files = harness.cell_files(BENCH, harness.find_cell(
+        BENCH, "lenet5.sweep-proposed"), ROOT)
+    files["traffic"] = json.loads(TRIMMED_TRAFFIC.read_text())
     return models.of(files["config"]).test_size(files)
 
 
@@ -46,6 +61,17 @@ def broken(monkeypatch):
     original = RoundEngine.block_step
 
     def plant(kind, classes):
+        if kind == "mean_for_trimmed":
+            import jax.numpy as jnp
+            from repro.core import aggregators
+            from repro.kernels import ops
+
+            def mean(self, grads, cweights):
+                n = (cweights > 0).sum().astype(jnp.float32)
+                return (ops.packed_weighted_grad_sum(grads, cweights) / n,
+                        jnp.int32(0))
+            monkeypatch.setattr(aggregators.TrimmedMean, "reduce", mean)
+            return
         if kind == "wrong_labels":
             client_data_class = experiment.ClientData
 
@@ -108,3 +134,31 @@ def test_the_control_fails_the_limits(cell):
     assert harness.judge(got["program"], files["limits"])[0], got["program"]
     assert not harness.judge(got["control"], files["limits"])[0], \
         got["control"]
+
+
+@pytest.mark.parametrize("fault", [None, "mean_for_trimmed"])
+def test_a_trimmed_mean_run_is_correct_and_not_with_the_mean_in_its_place(
+        fault, broken):
+    files = trimmed_files()
+    if fault:
+        broken(fault, files["config"]["data"]["classes"])
+    out = run("lenet5.sweep-proposed", files)
+    assert out["correct"] is (fault is None), out["compared"]
+    assert out["compared"]["unchecked_programs"]["value"] == 0
+
+
+def test_every_stand_in_fails_the_limits_of_a_trimmed_mean_cell():
+    files = trimmed_files()
+    cfg, tr = files["config"], files["traffic"]
+    _, env, r, images = harness.set_up(cfg, tr, 6)
+    check = harness.run_check(r, cfg, tr, 6)
+    stand_ins = harness.stand_ins_of(tr)
+    assert stand_ins == ("control", "half_batch", "mean_for_trimmed")
+    got = harness.readings(check, r.trainer.clients, images, cfg, tr,
+                           stand_ins)
+    for v in got.values():
+        v["unchecked_programs"] = 0
+    assert harness.judge(got["program"], files["limits"])[0], got["program"]
+    for name in stand_ins:
+        assert not harness.judge(got[name], files["limits"])[0], \
+            (name, got[name])

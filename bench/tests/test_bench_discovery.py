@@ -102,3 +102,44 @@ def test_unit_seeds_are_fixed_and_distinct_for_large_seeds():
     assert len(set(a)) == 5
     assert all(0 <= x < 2 ** 31 for x in a)
     assert harness.unit_seed(s + 1, 0) != a[0]
+
+
+def test_a_new_cell_reports_the_whole_step_metrics_with_no_list_naming_it():
+    """A cell that a later change adds gets `mfu`, `engine.dispatch_ms`,
+    `device.idle_share` and `device.peak_hbm_gib` without an edit to their
+    entries, and their readers give it numbers."""
+    from bench import flops
+    from bench.trace import TraceSummary
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": "lenet5.later-cell",
+                               "config": "lenet5-mnist",
+                               "traffic": "fixsel-c20", "chips": 1,
+                               "why": "x"})
+    cell = harness.find_cell(bench, "lenet5.later-cell")
+    every = ["mfu", "engine.dispatch_ms", "device.idle_share",
+             "device.peak_hbm_gib"]
+    entries = {m["name"]: m for m in
+               harness.cell_metrics(bench, cell, "per_layer")}
+    assert set(every) <= set(entries)
+    assert not any("workloads" in entries[m] for m in every)
+    cfg = json.loads((ROOT / "bench/configs/lenet5-mnist.json").read_text())
+    traffic = json.loads((ROOT / "bench/traffic/fixsel-c20.json").read_text())
+    traffic["evaluate"] = False
+    spans = harness.Spans()
+    spans.items += [("engine.dispatch", 1.0, 1.002),
+                    ("engine.dispatch", 1.1, 1.104),
+                    ("engine.dispatch", 1.2, 1.203)]
+    ctx = harness.Context(
+        spans=spans, window=(0.5, 2.5), rounds=120, units=2,
+        compiles_in_window=0, memory_peak_bytes=3 * 2 ** 29,
+        trace=TraceSummary(window_s=0.5, busy_s=0.2, op_seconds={},
+                           op_calls={}, idle_gaps={}, devices=1),
+        traced_rounds=60, cfg=cfg, traffic=traffic,
+        chips=1, peaks=harness.peak_entry("TPU v5 lite"),
+        selected_per_round=[20] * 60)
+    got = {m: harness.load_reader(m, ROOT)(ctx) for m in every}
+    assert got["engine.dispatch_ms"] == pytest.approx(3.0)
+    assert got["device.idle_share"] == pytest.approx(60.0)
+    assert got["device.peak_hbm_gib"] == pytest.approx(1.5)
+    want = 100 * flops.train_flops(cfg, 20 * 32 * 60) / (0.5 * 197e12)
+    assert got["mfu"] == pytest.approx(want)
